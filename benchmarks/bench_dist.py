@@ -2,9 +2,11 @@
 
 The device count is locked at first jax use, so the measurements run in a
 child process with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-(the same trick the dist tests use); the parent relays the CSV rows. Three
-paths over the same graphs, all LBP (deterministic, so sweeps/sec is the
-clean unit):
+(the same trick the dist tests use); the parent relays the CSV rows. The
+child pins ``JAX_PLATFORMS=cpu`` (``benchmarks.common.cpu_child_env``), so
+every number here is a CPU number on any host, as the JSON's ``backend``
+field says. Three paths over the same graphs, all LBP (deterministic, so
+sweeps/sec is the clean unit):
 
 - **single**: the engine's reference backend on one device,
 - **sharded**: ``repro.dist`` shard_map backend, edge axis over 8 shards
@@ -23,7 +25,6 @@ trajectory is honest and a real multi-chip run slots into the same file.
 from __future__ import annotations
 
 import json
-import os
 import platform
 import subprocess
 import sys
@@ -115,15 +116,13 @@ def _child(full: bool) -> None:
 def run(full: bool = False, n_graphs: int = 0) -> None:
     """Parent entry (benchmarks.run registry): re-exec in a child with 8
     forced host devices and relay its output."""
+    from benchmarks.common import cpu_child_env
     del n_graphs
-    env = dict(os.environ,
-               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                          + " --xla_force_host_platform_device_count=8"))
     cmd = [sys.executable, "-m", "benchmarks.bench_dist", "--child"]
     if full:
         cmd.append("--full")
-    out = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                         timeout=3600)
+    out = subprocess.run(cmd, env=cpu_child_env(8), capture_output=True,
+                         text=True, timeout=3600)
     sys.stdout.write(out.stdout)
     if out.returncode != 0:
         sys.stderr.write(out.stderr[-4000:])

@@ -14,9 +14,10 @@ regime where LBP oscillates and scheduling decides convergence):
    sorts.
 
 The relaxation section also runs a **collective audit** in an 8-forced-
-host-device child (same trick as ``bench_dist``): one BP round (sharded
-update + frontier select + commit) is jitted and compiled for rbp and rlx
-under ``backend="sharded"``, and the optimized HLO is scanned for
+host-device child pinned to the CPU (same trick as ``bench_dist``; the
+audit records ``backend: cpu``): one BP round (sharded update + frontier
+select + commit) is jitted and compiled for rbp and rlx under
+``backend="sharded"``, and the optimized HLO is scanned for
 cross-shard data movement (``all-gather``/``all-to-all``). RBP's exact
 global top-k forces the residual vector to be gathered across shards;
 rlx's per-queue top-k must not -- ``eliminates_global_topk`` in the JSON
@@ -30,7 +31,6 @@ restores paper scale.
 from __future__ import annotations
 
 import json
-import os
 import platform
 import subprocess
 import sys
@@ -148,7 +148,8 @@ def _audit_child() -> None:
     update_fn = make_sharded_update(mesh)
     pgm = shard_pgm(ising_grid_fast(16, 2.5, seed=0), mesh)
     n_edges = pgm.n_edges
-    report = {"devices": int(mesh.devices.size), "edge_count": n_edges}
+    report = {"backend": jax.default_backend(),
+              "devices": int(mesh.devices.size), "edge_count": n_edges}
     shape_re = re.compile(r"=\s+\w+\[([\d,]*)\]")
 
     def out_elems(line: str) -> int:
@@ -198,12 +199,10 @@ def _audit_child() -> None:
 
 
 def _run_audit() -> dict:
-    env = dict(os.environ,
-               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                          + " --xla_force_host_platform_device_count=8"))
+    from benchmarks.common import cpu_child_env
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_tradeoff", "--child-audit"],
-        env=env, capture_output=True, text=True, timeout=1200)
+        env=cpu_child_env(8), capture_output=True, text=True, timeout=1200)
     if out.returncode != 0:
         sys.stderr.write(out.stderr[-4000:])
         raise RuntimeError("bench_tradeoff audit child failed")

@@ -15,6 +15,7 @@ from benchmarks import (bench_batch, bench_correctness, bench_dist,
                         bench_greedy, bench_kernel, bench_protein,
                         bench_rnbp, bench_router, bench_sla,
                         bench_tradeoff, bench_zoo)
+from repro.compile_cache import use_compile_cache
 
 SUITES = {
     "fig2_tradeoff": bench_tradeoff,
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--graphs", type=int, default=0,
                     help="override graphs per dataset")
     args = ap.parse_args()
+    use_compile_cache()
     only = [s for s in args.only.split(",") if s]
     print("name,us_per_call,derived")
     for name, mod in SUITES.items():

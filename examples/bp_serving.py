@@ -65,6 +65,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import (BPConfig, BPEngine, list_admission_policies,
                         list_backends, list_schedulers, serve_async)
 from repro.pgm import (chain_graph, get_workload, ising_grid, list_workloads,
@@ -93,6 +94,7 @@ def request_stream(n, workload="legacy"):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=9)
     ap.add_argument("--eps", type=float, default=1e-3)

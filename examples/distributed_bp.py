@@ -25,6 +25,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.core import BPConfig, BPEngine, LBP, RnBP
 from repro.dist import make_bp_mesh, run_bp_sharded
 from repro.dist.bp_banded import partition_banded, run_bp_banded
@@ -32,6 +33,7 @@ from repro.pgm import ising_grid_fast
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--size", type=int, default=32,
                     help="Ising grid side (default 32; paper-ish scale 48+)")

@@ -25,12 +25,14 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import BPConfig, BPEngine, list_schedulers
 from repro.core.messages import map_assignment
 from repro.pgm import ldpc_code
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=48, help="code length (bits)")
     ap.add_argument("--dv", type=int, default=3, help="bit degree")

@@ -15,12 +15,14 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import BPConfig, BPEngine
 
 from repro.pgm import ising_grid
 
 
 def main():
+    use_compile_cache()
     # C controls difficulty (paper SSIII-C); this instance is in the regime
     # where synchronous LBP oscillates forever but randomized scheduling
     # converges (paper Fig 4b)

@@ -23,12 +23,14 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import BPConfig, BPEngine, list_schedulers
 from repro.core.messages import map_assignment
 from repro.pgm import stereo_mrf
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--height", type=int, default=12)
     ap.add_argument("--width", type=int, default=16)

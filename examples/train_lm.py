@@ -15,6 +15,7 @@ import time
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.checkpoint import latest_step, restore_pytree, save_pytree
 from repro.configs import get
 from repro.configs.base import TRAIN_4K
@@ -24,6 +25,7 @@ from repro.train.step import init_train_state, make_train_step
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_4b")
     ap.add_argument("--steps", type=int, default=60)

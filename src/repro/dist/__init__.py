@@ -35,8 +35,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import messages as M
 from repro.core.engine import BPConfig, BPEngine, BPResult, BPState
@@ -58,9 +57,13 @@ def make_bp_mesh(n_devices: int | None = None, *,
     named ``axis`` (default ``"bp"``), using the first ``n_devices`` of
     ``jax.devices()`` (all of them when ``None``). Works with any device
     count, including ``--xla_force_host_platform_device_count`` CPU meshes.
+
+    The axis is ``AxisType.Auto``: the engine's gathers and scatters over
+    sharded edge arrays (``init_messages``, the banded scatter-back) leave
+    their output layout to the compiler, which explicit axes refuse.
     """
     n = n_devices or len(jax.devices())
-    return jax.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,))
 
 
 def _check_edge_layout(pgm: PGM, n_shards: int) -> None:
@@ -162,12 +165,12 @@ def make_sharded_update(mesh: Mesh | None = None, *, axis: str = BP_AXIS):
             return M.normalize_and_residual(cand, logm_sh, smask[dst], emask)
 
         es, es2 = P(axis), P(axis, None)
-        return shard_map(
+        return jax.shard_map(
             body, mesh=m,
             in_specs=(es, es, es, es, P(axis, None, None),
                       P(None, None), P(None, None), es2),
             out_specs=(es2, es),
-            check_rep=False)(
+            check_vma=False)(
             pgm.edge_src, pgm.edge_dst, pgm.edge_rev, pgm.edge_mask,
             pgm.log_psi_e, pgm.log_psi_v, pgm.state_mask, logm)
 

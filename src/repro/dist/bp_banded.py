@@ -52,7 +52,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import messages as M
@@ -344,12 +343,12 @@ def run_bp_banded(part: BandedPartition, scheduler, mesh: Mesh,
         logm, rounds, done, _, _ = jax.lax.while_loop(cond, bp_round, init)
         return logm, rounds, done
 
-    sharded = jax.jit(shard_map(
+    sharded = jax.jit(jax.shard_map(
         body_shard, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis),
                   P(axis), P(axis), P(None, None), P(None, None), P()),
         out_specs=(P(axis, None), P(), P()),
-        check_rep=False))
+        check_vma=False))
     slots = part.slot_edge.reshape(-1)
     live = np.flatnonzero(slots >= 0)
 

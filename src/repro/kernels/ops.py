@@ -2,8 +2,8 @@
 
 ``pallas_update(pgm, logm)`` is a drop-in replacement for
 ``repro.core.messages.ref_update`` (same (E, S) layout at the boundary); it
-handles the transpose to kernel layout, edge padding to the block size, and
-interpret-mode fallback off-TPU.
+handles the transpose to kernel layout and edge padding to the block size.
+On the CPU it runs the Pallas interpreter; on a TPU it compiles the kernel.
 
 ``pallas_update_t`` is the layout-native variant used by the perf-tuned BP
 loop, which keeps messages transposed (S, E) across rounds so the two
@@ -20,7 +20,7 @@ per-graph remainder waste). ``make_pallas_update_batch`` packages it as a
 (``repro.kernels.triton_update``): same fused pipeline in the engine's
 native edge-major layout (zero boundary transposes), blocked over edges
 with states in registers, lowered through Pallas's Triton path on CUDA
-devices and through the interpreter everywhere else -- plus a
+devices and through the interpreter on the CPU -- plus a
 ``semiring="max"`` mode so MAP workloads run fused too. Registered as
 ``"triton"`` in both registries; ``BPConfig(backend="triton")`` reaches it.
 """
@@ -45,12 +45,33 @@ __all__ = ["UPDATE_BACKENDS", "BATCH_UPDATE_BACKENDS", "kernel_operands_t",
            "register_update_backend", "list_backends", "get_update_fn"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+#: The platform each fused kernel compiles for. On the CPU both run in the
+#: Pallas interpreter (the same program, for tests); anywhere else the
+#: kernel meant for that platform must be chosen -- an interpreter on an
+#: accelerator would be slow without saying so.
+_KERNEL_PLATFORM = {"pallas": "tpu", "triton": "gpu"}
 
 
-def _on_gpu() -> bool:
-    return jax.default_backend() == "gpu"
+def _platform() -> str:
+    return jax.default_backend()
+
+
+def _resolve_interpret(backend: str, interpret: bool | None) -> bool:
+    """``interpret`` for kernel ``backend`` ("pallas" | "triton"): an explicit
+    value wins; otherwise True on the CPU, False on the kernel's own
+    platform, and an error naming the right backend anywhere else."""
+    if interpret is not None:
+        return interpret
+    platform = _platform()
+    if platform == "cpu":
+        return True
+    if platform == _KERNEL_PLATFORM[backend]:
+        return False
+    meant = [b for b, p in _KERNEL_PLATFORM.items() if p == platform]
+    raise RuntimeError(
+        f"backend {backend!r} is the {_KERNEL_PLATFORM[backend]} kernel and "
+        f"would only be interpreted on {platform!r}; use backend "
+        f"{(meant or ['ref'])[0]!r} there (or pass interpret= explicitly)")
 
 
 def kernel_operands_t(pgm: PGM):
@@ -63,8 +84,7 @@ def kernel_operands_t(pgm: PGM):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pallas_update(pgm: PGM, logm: jax.Array, *, interpret: bool | None = None):
     """(cand (E,S), resid (E,)) -- kernel-backed ref_update equivalent."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _resolve_interpret("pallas", interpret)
     pre = M.edge_prelude(pgm, logm)                          # (E, S)
     logpsi_t, dmask_t = kernel_operands_t(pgm)
     new_t, resid = fused_update_t(
@@ -74,8 +94,7 @@ def pallas_update(pgm: PGM, logm: jax.Array, *, interpret: bool | None = None):
 
 def make_pallas_update(interpret: bool | None = None):
     """Static-arg-free closure suitable for ``run_bp(update_fn=...)``."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _resolve_interpret("pallas", interpret)
 
     def update_fn(pgm: PGM, logm: jax.Array):
         return pallas_update(pgm, logm, interpret=interpret)
@@ -90,8 +109,7 @@ def pallas_update_batch(bpgm: PGM, logm: jax.Array, *,
     carry a leading batch axis (``BatchedPGM.pgm``). The batch axis is folded
     into the kernel's edge axis: one launch, grid = ceil(B*E / BLK_E).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _resolve_interpret("pallas", interpret)
     b, e, s = logm.shape
     pre = jax.vmap(M.edge_prelude)(bpgm, logm)                # (B, E, S)
     # Fold batch into edges: graph b's edge e becomes folded edge b*E + e.
@@ -107,8 +125,7 @@ def pallas_update_batch(bpgm: PGM, logm: jax.Array, *,
 def make_pallas_update_batch(interpret: bool | None = None):
     """``batch_update_fn`` closure for the engine's batched path: whole-
     bucket fused message update in one kernel launch."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _resolve_interpret("pallas", interpret)
 
     def batch_update_fn(bpgm: PGM, logm: jax.Array):
         return pallas_update_batch(bpgm, logm, interpret=interpret)
@@ -125,8 +142,7 @@ def triton_update(pgm: PGM, logm: jax.Array, *, interpret: bool | None = None,
     """(cand (E,S), resid (E,)) -- GPU-kernel-backed ``ref_update`` (or, with
     ``semiring="max"``, ``max_product_update``) equivalent. Edge-major all
     the way: no layout transposes at the boundary."""
-    if interpret is None:
-        interpret = not _on_gpu()
+    interpret = _resolve_interpret("triton", interpret)
     pre = M.edge_prelude(pgm, logm)                          # (E, S)
     dmask = pgm.state_mask[pgm.edge_dst]                     # (E, S)
     return fused_update_e(pgm.log_psi_e, pre, logm, dmask,
@@ -137,10 +153,9 @@ def triton_update(pgm: PGM, logm: jax.Array, *, interpret: bool | None = None,
 def make_triton_update(interpret: bool | None = None, *,
                        semiring: str = "sum", blk_e: int | None = None):
     """Static-arg-free closure for ``BPConfig(backend="triton")``: resolves
-    ``interpret`` once (Triton lowering on GPU, interpreter elsewhere) so
+    ``interpret`` once (Triton lowering on GPU, interpreter on the CPU) so
     the returned callable is jit-cache-stable."""
-    if interpret is None:
-        interpret = not _on_gpu()
+    interpret = _resolve_interpret("triton", interpret)
 
     def update_fn(pgm: PGM, logm: jax.Array):
         return triton_update(pgm, logm, interpret=interpret,
@@ -157,8 +172,7 @@ def triton_update_batch(bpgm: PGM, logm: jax.Array, *,
     """(cand (B,E,S), resid (B,E)) bucket path: the batch axis folds into
     the kernel's edge grid (one launch of ceil(B*E / BLK_E) programs), same
     fold as ``pallas_update_batch`` but with zero transposes."""
-    if interpret is None:
-        interpret = not _on_gpu()
+    interpret = _resolve_interpret("triton", interpret)
     b, e, s = logm.shape
     pre = jax.vmap(M.edge_prelude)(bpgm, logm)                # (B, E, S)
     dmask = jax.vmap(lambda p: p.state_mask[p.edge_dst])(bpgm)
@@ -173,8 +187,7 @@ def make_triton_update_batch(interpret: bool | None = None, *,
                              semiring: str = "sum", blk_e: int | None = None):
     """``batch_update_fn`` closure: whole-bucket fused edge-major update in
     one kernel launch (the ``"triton"`` batched registry entry)."""
-    if interpret is None:
-        interpret = not _on_gpu()
+    interpret = _resolve_interpret("triton", interpret)
 
     def batch_update_fn(bpgm: PGM, logm: jax.Array):
         return triton_update_batch(bpgm, logm, interpret=interpret,
@@ -185,7 +198,7 @@ def make_triton_update_batch(interpret: bool | None = None, *,
 
 # ------------------------------------------------- backend registry ------
 # Message-update backends addressable by BPConfig.backend string. "ref" is
-# the pure-jnp oracle; "pallas" the fused kernel (interpret-mode off-TPU).
+# the pure-jnp oracle; "pallas" the fused kernel (interpreted on the CPU).
 # Batched entries return a natively batched (B, E, S) update; the engine's
 # default batched path instead folds the bucket and reuses the single-graph
 # backend, so only register a batched entry when it beats the fold.
@@ -208,8 +221,8 @@ UPDATE_BACKENDS = Registry("update backend", {
     "maxprod": lambda: M.max_product_update,
     "pallas": make_pallas_update,
     # GPU-class fused kernel (Pallas Triton lowering, edge-major blocks,
-    # states in registers; interpret-mode everywhere off-GPU so CPU CI
-    # exercises the same program). semiring="max" kwarg serves MAP.
+    # states in registers; interpreted on the CPU so CPU CI exercises the
+    # same program). semiring="max" kwarg serves MAP.
     "triton": make_triton_update,
     # Multi-device shard_map update over the edge axis (repro.dist). With
     # no kwargs a mesh over all devices is built at resolve time, so

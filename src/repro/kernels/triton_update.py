@@ -41,10 +41,11 @@ VMEM budget does. ``autotune_blk_e`` measures candidates around that
 prediction; ``benchmarks/bench_kernel.py`` records predicted-vs-measured
 arithmetic intensity per scheduler into ``BENCH_kernel.json``.
 
-Off-GPU the kernel runs in ``interpret=True`` mode (CPU CI exercises the
+On the CPU the kernel runs in ``interpret=True`` mode (CPU CI exercises the
 same program through the Pallas interpreter), so ``BPConfig(
-backend="triton")`` is usable -- and differentially tested against the
-reference path -- everywhere; on a CUDA device the identical program lowers
+backend="triton")`` is differentially tested against the reference path;
+on a TPU it refuses to run and points to ``"pallas"``
+(``repro.kernels.ops``). On a CUDA device the identical program lowers
 through Triton with ``plgpu.CompilerParams`` (num_warps scaled to the
 tile, ``num_stages=2`` for double-buffered HBM streaming).
 """

@@ -26,7 +26,8 @@ except ImportError:                       # degrade: property tests skip
 from repro.core import messages as M
 from repro.core.graph import NEG_INF
 from repro.kernels.message_update import fused_update_t, pick_block_edges
-from repro.kernels.ops import make_pallas_update, pallas_update
+from repro.kernels import ops
+from repro.kernels.ops import get_update_fn, make_pallas_update, pallas_update
 from repro.kernels.ref import fused_update_t_ref
 from repro.pgm import ising_grid, protein_like_graph
 
@@ -127,6 +128,40 @@ class TestKernelInBP:
         # so the fixed points agree to ~degree * eps
         np.testing.assert_allclose(np.asarray(r_ref.beliefs),
                                    np.asarray(r_k.beliefs), atol=1e-4)
+
+
+class TestInterpretRule:
+    """Where each fused kernel interprets: on the CPU both do (the tests'
+    path); on an accelerator only that platform's own kernel runs, and
+    asking for the other one raises an error naming the right backend."""
+
+    @pytest.mark.parametrize("backend", ["pallas", "triton"])
+    def test_cpu_interprets(self, backend):
+        assert jax.default_backend() == "cpu"
+        pgm = ising_grid(4, 1.5, seed=0)
+        logm = M.init_messages(pgm)
+        cand, resid = get_update_fn(backend)(pgm, logm)
+        c_r, r_r = M.ref_update(pgm, logm)
+        mask = np.asarray(pgm.state_mask[pgm.edge_dst])
+        np.testing.assert_allclose(np.where(mask, np.asarray(cand), 0.0),
+                                   np.where(mask, np.asarray(c_r), 0.0),
+                                   atol=1e-5)
+        np.testing.assert_allclose(np.asarray(resid), np.asarray(r_r),
+                                   atol=1e-5)
+
+    @pytest.mark.parametrize("platform,own,other",
+                             [("tpu", "pallas", "triton"),
+                              ("gpu", "triton", "pallas")])
+    def test_accelerator_runs_only_its_kernel(self, monkeypatch, platform,
+                                              own, other):
+        monkeypatch.setattr(ops, "_platform", lambda: platform)
+        assert ops._resolve_interpret(own, None) is False
+        with pytest.raises(RuntimeError, match=repr(own)):
+            get_update_fn(other)
+        with pytest.raises(RuntimeError, match=repr(own)):
+            get_update_fn(other, batched=True)
+        # an explicit interpret= still wins
+        assert callable(get_update_fn(other, interpret=True))
 
 
 class TestKernelProperties:

@@ -1,11 +1,6 @@
 """Optimized-path equivalence: the SSPerf variants must compute the same
 math as the baselines they replace."""
 
-import dataclasses
-import os
-import subprocess
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,13 +28,9 @@ class TestMoEDispatchParity:
         np.testing.assert_allclose(float(aux_r[0]), float(aux_d[0]),
                                    rtol=1e-5)
 
-    def test_sharded_equals_ragged_subprocess(self):
+    def test_sharded_equals_ragged_subprocess(self, run_on_cpu_devices):
         """sharded dispatch on 4 fake devices == ragged on one."""
-        if not hasattr(jax, "shard_map"):
-            pytest.skip("jax.shard_map requires a newer jax")
         code = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.models.layers.moe import init_moe, moe, set_shard_mesh
@@ -59,26 +50,19 @@ np.testing.assert_allclose(np.asarray(out_r), np.asarray(out_s),
                            atol=1e-4, rtol=1e-4)
 print("OK")
 """
-        env = dict(os.environ,
-                   PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                           "src"))
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=600)
-        assert out.returncode == 0, out.stderr[-2000:]
-        assert "OK" in out.stdout
+        run_on_cpu_devices(code, n_devices=4, timeout=600)
 
 
 class TestBandedBP:
-    def test_banded_matches_reference_subprocess(self):
+    def test_banded_matches_reference_subprocess(self, run_on_cpu_devices):
         code = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, numpy as np
 from repro.core import LBP, run_bp
 from repro.pgm import ising_grid_fast, chain_graph
+from repro.dist import make_bp_mesh
 from repro.dist.bp_banded import partition_banded, run_bp_banded
 
-mesh = jax.make_mesh((8,), ("bp",))
+mesh = make_bp_mesh(8)
 for pgm in [ising_grid_fast(24, 2.5, seed=0), chain_graph(2000, seed=0)]:
     ref = run_bp(pgm, LBP(), jax.random.key(0), eps=1e-5, max_rounds=6000)
     part = partition_banded(pgm, 8)
@@ -90,13 +74,7 @@ for pgm in [ising_grid_fast(24, 2.5, seed=0), chain_graph(2000, seed=0)]:
     assert int(rounds) == int(ref.rounds), (int(rounds), int(ref.rounds))
 print("OK")
 """
-        env = dict(os.environ,
-                   PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                           "src"))
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=600)
-        assert out.returncode == 0, out.stderr[-2000:]
-        assert "OK" in out.stdout
+        run_on_cpu_devices(code, timeout=600)
 
     def test_partition_rejects_unbanded(self):
         from repro.dist.bp_banded import partition_banded
@@ -105,19 +83,18 @@ print("OK")
         with pytest.raises(AssertionError):
             partition_banded(pgm, 32)
 
-    def test_banded_relaxed_converges_subprocess(self):
+    def test_banded_relaxed_converges_subprocess(self, run_on_cpu_devices):
         # rlx/rlxtree are first-class on the banded path: shard-local
         # per-queue top-k, no global sort. Same 8-fake-device subprocess
         # pattern as the LBP parity test above.
         code = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from repro.core import RLX, RLXTree
 from repro.pgm import ising_grid_fast
+from repro.dist import make_bp_mesh
 from repro.dist.bp_banded import partition_banded, run_bp_banded
 
-mesh = jax.make_mesh((8,), ("bp",))
+mesh = make_bp_mesh(8)
 pgm = ising_grid_fast(24, 2.5, seed=0)
 part = partition_banded(pgm, 8)
 for sched in [RLX(), RLXTree()]:
@@ -126,21 +103,16 @@ for sched in [RLX(), RLXTree()]:
     assert bool(done), f"banded {type(sched).__name__} did not converge"
 print("OK")
 """
-        env = dict(os.environ,
-                   PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                           "src"))
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=600)
-        assert out.returncode == 0, out.stderr[-2000:]
-        assert "OK" in out.stdout
+        run_on_cpu_devices(code, timeout=600)
 
     def test_banded_unsupported_scheduler_error_lists_rlx(self):
         # exact sort-based schedulers are rejected with the uniform
         # registry-style message that names the supported subset
         from repro.core import RBP, RS
+        from repro.dist import make_bp_mesh
         from repro.dist.bp_banded import partition_banded, run_bp_banded
         from repro.pgm import ising_grid_fast
-        mesh = jax.make_mesh((1,), ("bp",))
+        mesh = make_bp_mesh(1)
         part = partition_banded(ising_grid_fast(6, 1.0, seed=0), 1)
         for sched in (RBP(), RS()):
             with pytest.raises(NotImplementedError) as ei:

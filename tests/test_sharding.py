@@ -70,10 +70,7 @@ class TestTreeShardings:
     def test_batch_replicates_when_indivisible(self):
         # B=1 (long_500k) cannot shard over the data axis -> replicate.
         # AbstractMesh: sharding metadata without needing 2 real devices.
-        try:
-            mesh = jax.sharding.AbstractMesh((2, 1), ("data", "model"))
-        except TypeError:
-            pytest.skip("AbstractMesh(axis_sizes, axis_names) needs newer jax")
+        mesh = jax.sharding.AbstractMesh((2, 1), ("data", "model"))
         sh = batch_shardings(
             mesh, {"tokens": jax.ShapeDtypeStruct((1, 1), jnp.int32)})
         assert sh["tokens"].spec == P(None, None)

@@ -3,8 +3,6 @@ checkpoint/restore, data-pipeline determinism, fault-tolerance paths."""
 
 import dataclasses
 import os
-import subprocess
-import sys
 import tempfile
 
 import jax
@@ -22,12 +20,10 @@ from repro.pgm import ising_grid
 
 
 class TestDistributedBP:
-    def test_sharded_bp_matches_single_device(self):
+    def test_sharded_bp_matches_single_device(self, run_on_cpu_devices):
         """Runs in a subprocess with 8 forced host devices (device count is
         locked at first jax use, so it cannot be set in-process)."""
         code = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import RnBP, LBP, run_bp
 from repro.pgm import ising_grid
@@ -46,21 +42,13 @@ for sched in [LBP(), RnBP(low_p=0.7)]:
     assert d < 5e-3, (type(sched).__name__, d)
 print("OK")
 """
-        env = dict(os.environ,
-                   PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                           "src"))
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=900)
-        assert out.returncode == 0, out.stderr[-2000:]
-        assert "OK" in out.stdout
+        run_on_cpu_devices(code)
 
-    def test_sharded_relaxed_scheduler_converges(self):
+    def test_sharded_relaxed_scheduler_converges(self, run_on_cpu_devices):
         """rlx/rlxtree under the 8-device sharded backend: converge to the
         single-device beliefs, and chunked resume stays bitwise (the relaxed
         per-queue selection must survive the shard_map backend)."""
         code = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import LBP, run_bp
 from repro.pgm import ising_grid
@@ -87,21 +75,13 @@ for name in ["rlx", "rlxtree"]:
                                   np.asarray(chunked.logm))
 print("OK")
 """
-        env = dict(os.environ,
-                   PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                           "src"))
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=900)
-        assert out.returncode == 0, out.stderr[-2000:]
-        assert "OK" in out.stdout
+        run_on_cpu_devices(code)
 
-    def test_sharded_chunked_resume_bitwise(self):
+    def test_sharded_chunked_resume_bitwise(self, run_on_cpu_devices):
         """Chunked BPEngine.step under the 8-device mesh must match a
         monolithic sharded run bit-for-bit -- the engine's resume guarantee
         has to survive the shard_map backend."""
         code = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, numpy as np
 from repro.pgm import ising_grid
 from repro.dist import make_bp_mesh, make_sharded_engine, shard_pgm
@@ -126,21 +106,13 @@ np.testing.assert_array_equal(np.asarray(mono.beliefs),
                               np.asarray(chunked.beliefs))
 print("OK")
 """
-        env = dict(os.environ,
-                   PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                           "src"))
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=900)
-        assert out.returncode == 0, out.stderr[-2000:]
-        assert "OK" in out.stdout
+        run_on_cpu_devices(code)
 
-    def test_batched_bucket_through_sharded_fold(self):
+    def test_batched_bucket_through_sharded_fold(self, run_on_cpu_devices):
         """run_many with backend='sharded' routes whole buckets through the
         mesh-aware disjoint-union fold; per-graph beliefs must match the
         single-device engine within the sharded tolerance."""
         code = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from repro.core import BPConfig, BPEngine
 from repro.pgm import ising_grid
@@ -159,22 +131,14 @@ for s, r in zip(res_s, res_r):
     assert d < 5e-3, d
 print("OK")
 """
-        env = dict(os.environ,
-                   PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                           "src"))
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=900)
-        assert out.returncode == 0, out.stderr[-2000:]
-        assert "OK" in out.stdout
+        run_on_cpu_devices(code)
 
-    def test_async_serving_through_sharded_backend(self):
+    def test_async_serving_through_sharded_backend(self, run_on_cpu_devices):
         """The async pipeline composes with backend='sharded': every
         resident bucket's union grid is laid out over the mesh, and
         evacuation + compaction work unchanged (per-graph beliefs match the
         single-device pipeline within the sharded tolerance)."""
         code = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from repro.core import BPConfig, BPEngine, serve_async
 from repro.pgm import ising_grid
@@ -196,13 +160,7 @@ for s, r in zip(rep_s.results, rep_r.results):
     assert d < 5e-3, d
 print("OK")
 """
-        env = dict(os.environ,
-                   PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
-                                           "src"))
-        out = subprocess.run([sys.executable, "-c", code], env=env,
-                             capture_output=True, text=True, timeout=900)
-        assert out.returncode == 0, out.stderr[-2000:]
-        assert "OK" in out.stdout
+        run_on_cpu_devices(code)
 
 
 class TestCheckpoint:

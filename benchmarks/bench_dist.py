@@ -3,7 +3,7 @@
 The device count is locked at first jax use, so the measurements run in a
 child process with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
 (the same trick the dist tests use); the parent relays the CSV rows. The
-child pins ``JAX_PLATFORMS=cpu`` (``benchmarks.common.cpu_child_env``), so
+child pins ``JAX_PLATFORMS=cpu`` (``repro.dist.cpu_child_env``), so
 every number here is a CPU number on any host, as the JSON's ``backend``
 field says. Three paths over the same graphs, all LBP (deterministic, so
 sweeps/sec is the clean unit):
@@ -116,7 +116,7 @@ def _child(full: bool) -> None:
 def run(full: bool = False, n_graphs: int = 0) -> None:
     """Parent entry (benchmarks.run registry): re-exec in a child with 8
     forced host devices and relay its output."""
-    from benchmarks.common import cpu_child_env
+    from repro.dist import cpu_child_env
     del n_graphs
     cmd = [sys.executable, "-m", "benchmarks.bench_dist", "--child"]
     if full:

@@ -19,8 +19,10 @@ committed root copy, since ``benchmarks/out/`` is gitignored):
   ``pick_block_edges_gpu`` choice. On CPU (interpret mode) wall time is
   not the GPU story, so the recorded claim is only that the model pick is
   admissible (a swept candidate); on a real GPU the sweep re-runs there.
-- **walltime**: ref vs pallas-interpret vs triton-interpret microseconds
-  for one update call (CPU sanity numbers, not the accelerator story).
+- **walltime**: microseconds for one update call through ``ref`` and
+  through each fused kernel that runs on this platform: compiled on its
+  own platform, interpreted on the CPU (``_interp`` keys: CPU sanity
+  numbers, not the accelerator story), left out elsewhere.
 
 Usage: python -m benchmarks.bench_kernel [--tiny | --full]
 """
@@ -79,6 +81,19 @@ def _kernel_row(e, s, *, dtype=jnp.float32, semiring="sum"):
                 prediction_within_tolerance=bool(rel <= _RTOL))
 
 
+def _update_paths() -> dict:
+    """Wall-timed update paths on this platform. The kernels resolve
+    ``interpret`` themselves (interpreter on the CPU only); a kernel meant
+    for another accelerator is left out rather than interpreted on it."""
+    here = jax.default_backend()
+    paths = {"ref": M.ref_update}
+    for name, fn, platform in (("pallas", pallas_update, "tpu"),
+                               ("triton", triton_update, "gpu")):
+        if here in ("cpu", platform):
+            paths[name + ("_interp" if here == "cpu" else "")] = fn
+    return paths
+
+
 def _time_update(fn, pgm, logm, iters=5):
     out = fn(pgm, logm)
     jax.block_until_ready(out)
@@ -104,7 +119,8 @@ def run(full: bool = False, n_graphs: int = 1, tiny: bool = False) -> None:
                            jax=jax.__version__,
                            machine=platform.machine(),
                            backend=jax.default_backend(),
-                           interpret=True, rtol=_RTOL),
+                           interpret=jax.default_backend() == "cpu",
+                           rtol=_RTOL),
               "kernel": {}, "schedulers": {}, "autotune": {},
               "walltime": {}}
 
@@ -146,35 +162,33 @@ def run(full: bool = False, n_graphs: int = 1, tiny: bool = False) -> None:
              f"pred_ai={pred_ai:.3f};meas_ai={meas_ai:.3f};"
              f"round_ai={round_ai:.3f};ok={rel <= _RTOL}")
 
-    # -- autotune: model pick vs wall-time sweep -------------------------
-    key = jax.random.key(0)
-    _, s_pad, model_blk = gpu_padded_shape(e, s)   # model pick, launch-clamped
-    logpsi = jax.random.normal(key, (e, s, s))
-    pre = jax.random.normal(jax.random.fold_in(key, 1), (e, s))
-    logm = jnp.zeros((e, s))
-    dmask = jnp.ones((e, s), dtype=bool)
-    best_blk, timings = autotune_blk_e(logpsi, pre, logm, dmask,
-                                       interpret=True,
-                                       iters=1 if tiny else 3)
-    record["autotune"] = dict(
-        case=cases[0][0], n_edges=e, n_states=s,
-        model_blk=model_blk, best_blk=best_blk,
-        model_pick_swept=bool(model_blk in timings),
-        target_intensity=predicted_intensity(s, padded=True),
-        timings_us={str(k): v for k, v in sorted(timings.items())})
-    emit(f"kernel/autotune/{cases[0][0]}", min(timings.values()),
-         f"model_blk={model_blk};best_blk={best_blk}")
+    # -- autotune: model pick vs wall-time sweep, where the GPU kernel runs
+    paths = _update_paths()
+    triton_path = [p for p in paths if p.startswith("triton")]
+    if triton_path:
+        key = jax.random.key(0)
+        _, s_pad, model_blk = gpu_padded_shape(e, s)   # model pick, clamped
+        logpsi = jax.random.normal(key, (e, s, s))
+        pre = jax.random.normal(jax.random.fold_in(key, 1), (e, s))
+        logm = jnp.zeros((e, s))
+        dmask = jnp.ones((e, s), dtype=bool)
+        best_blk, timings = autotune_blk_e(
+            logpsi, pre, logm, dmask, iters=1 if tiny else 3,
+            interpret=triton_path == ["triton_interp"])
+        record["autotune"] = dict(
+            case=cases[0][0], n_edges=e, n_states=s,
+            model_blk=model_blk, best_blk=best_blk,
+            model_pick_swept=bool(model_blk in timings),
+            target_intensity=predicted_intensity(s, padded=True),
+            timings_us={str(k): v for k, v in sorted(timings.items())})
+        emit(f"kernel/autotune/{cases[0][0]}", min(timings.values()),
+             f"model_blk={model_blk};best_blk={best_blk}")
 
-    # -- walltime: CPU sanity, all three update paths --------------------
+    # -- walltime: the update paths that run on this platform ------------
     wt_cases = cases if not tiny else cases[:1]
     for name, pgm in wt_cases:
         logm_g = M.init_messages(pgm)
-        for path, fn in [
-                ("ref", M.ref_update),
-                ("pallas_interp",
-                 lambda p, m: pallas_update(p, m, interpret=True)),
-                ("triton_interp",
-                 lambda p, m: triton_update(p, m, interpret=True))]:
+        for path, fn in paths.items():
             us = _time_update(fn, pgm, logm_g, iters=2 if tiny else 5)
             record["walltime"][f"{name}/{path}"] = us
             emit(f"kernel/{name}/{path}", us,

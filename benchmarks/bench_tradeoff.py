@@ -199,7 +199,7 @@ def _audit_child() -> None:
 
 
 def _run_audit() -> dict:
-    from benchmarks.common import cpu_child_env
+    from repro.dist import cpu_child_env
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_tradeoff", "--child-audit"],
         env=cpu_child_env(8), capture_output=True, text=True, timeout=1200)

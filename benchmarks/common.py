@@ -15,7 +15,6 @@ reproduction targets; wall-clock ratios are secondary on CPU.
 from __future__ import annotations
 
 import dataclasses
-import os
 import pathlib
 import time
 from typing import Callable, Iterable, List, Sequence
@@ -33,17 +32,6 @@ def out_path(filename: str) -> pathlib.Path:
     d = pathlib.Path(__file__).resolve().parent / "out"
     d.mkdir(exist_ok=True)
     return d / filename
-
-
-def cpu_child_env(n_devices: int = 8) -> dict:
-    """Environment for a child process that fakes ``n_devices`` devices on
-    the host CPU. It pins ``JAX_PLATFORMS=cpu``: the parent may already
-    hold the accelerator, and a chip serves one process at a time, so such
-    a child's numbers are CPU numbers wherever it runs."""
-    return dict(os.environ, JAX_PLATFORMS="cpu",
-                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count="
-                           f"{n_devices}"))
 
 
 @dataclasses.dataclass

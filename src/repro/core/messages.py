@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.graph import NEG_INF, PGM
+from repro.kernels.explog import exp, log
 
 
 def masked_logsumexp(x: jax.Array, mask: jax.Array, axis: int) -> jax.Array:
@@ -32,15 +33,15 @@ def masked_logsumexp(x: jax.Array, mask: jax.Array, axis: int) -> jax.Array:
     x = jnp.where(mask, x, NEG_INF)
     m = jnp.max(x, axis=axis, keepdims=True)
     m = jnp.maximum(m, NEG_INF)  # all-masked rows stay finite
-    s = jnp.sum(jnp.where(mask, jnp.exp(x - m), 0.0), axis=axis)
-    return jnp.squeeze(m, axis) + jnp.log(jnp.maximum(s, 1e-38))
+    s = jnp.sum(jnp.where(mask, exp(x - m), 0.0), axis=axis)
+    return jnp.squeeze(m, axis) + log(s)
 
 
 def init_messages(pgm: PGM, dtype=jnp.float32) -> jax.Array:
     """Uniform messages over the *destination* vertex's valid states."""
     dst_mask = pgm.state_mask[pgm.edge_dst]                     # (E, S)
     n_dst = pgm.n_states[pgm.edge_dst].astype(dtype)            # (E,)
-    logm = jnp.where(dst_mask, -jnp.log(n_dst)[:, None], NEG_INF)
+    logm = jnp.where(dst_mask, -log(n_dst)[:, None], NEG_INF)
     return logm.astype(dtype)
 
 
@@ -74,8 +75,8 @@ def propagate_ref(log_psi_e: jax.Array, pre: jax.Array) -> jax.Array:
     scores = log_psi_e + pre[:, :, None]          # (E, S, S)
     m = jnp.max(scores, axis=1, keepdims=True)
     m = jnp.maximum(m, NEG_INF)
-    s = jnp.sum(jnp.exp(scores - m), axis=1)
-    return jnp.squeeze(m, 1) + jnp.log(jnp.maximum(s, 1e-38))
+    s = jnp.sum(exp(scores - m), axis=1)
+    return jnp.squeeze(m, 1) + log(s)
 
 
 def normalize_messages(pgm: PGM, cand: jax.Array) -> jax.Array:

@@ -32,6 +32,8 @@ communication suffices and LBP trajectories are reproduced round-exactly.
 
 from __future__ import annotations
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -47,6 +49,17 @@ from repro.dist.bp_banded import (BandedPartition, partition_banded,
 
 #: Default mesh axis name for the sharded edge dimension.
 BP_AXIS = "bp"
+
+
+def cpu_child_env(n_devices: int = 8) -> dict:
+    """Environment for a child process that fakes ``n_devices`` devices on
+    the host CPU. It pins ``JAX_PLATFORMS=cpu``: the parent may already
+    hold the accelerator, and a chip serves one process at a time, so such
+    a child's numbers are CPU numbers wherever it runs."""
+    return dict(os.environ, JAX_PLATFORMS="cpu",
+                XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count="
+                           f"{n_devices}"))
 
 
 def make_bp_mesh(n_devices: int | None = None, *,
@@ -232,7 +245,7 @@ def run_bp_sharded(pgm: PGM, scheduler: Scheduler | str, mesh: Mesh,
 
 
 __all__ = [
-    "BP_AXIS", "make_bp_mesh", "shard_pgm", "make_sharded_update",
-    "make_sharded_engine", "run_bp_sharded",
+    "BP_AXIS", "cpu_child_env", "make_bp_mesh", "shard_pgm",
+    "make_sharded_update", "make_sharded_engine", "run_bp_sharded",
     "BandedPartition", "partition_banded", "run_bp_banded",
 ]

@@ -59,6 +59,7 @@ from repro.core.graph import NEG_INF, PGM
 from repro.core.registry import Registry
 from repro.core.schedulers import LBP, RLX, RLXTree, RnBP, get_scheduler
 from repro.core.schedulers.rlx import queue_count, relaxed_frontier
+from repro.kernels.explog import log
 
 #: The scheduler subset the banded runner supports (see module docstring:
 #: exact sort-based priorities need a global top-k and are excluded).
@@ -288,7 +289,7 @@ def run_bp_banded(part: BandedPartition, scheduler, mesh: Mesh,
         base = (idx - 1) * L            # flattened coord of ext slot 0
         shard_key = jax.random.fold_in(
             jax.random.wrap_key_data(key_data), idx)
-        logm0 = jnp.where(smask[dst], -jnp.log(
+        logm0 = jnp.where(smask[dst], -log(
             pgm.n_states[dst].astype(jnp.float32))[:, None], NEG_INF)
 
         def cond(c):

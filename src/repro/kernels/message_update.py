@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.explog import exp, log
+
 NEG_INF = -1.0e30
 _LANE = 128
 _VMEM_BUDGET_BYTES = 4 * 1024 * 1024
@@ -58,17 +60,30 @@ def pick_block_edges(n_states: int, dtype_bytes: int = 4) -> int:
 
 def _fused_kernel(logpsi_ref, pre_ref, logm_ref, dmask_ref,
                   out_ref, resid_ref):
-    """Blocks: logpsi (S,S,Eb) [xi,xj,e]; pre/logm/dmask/out (S,Eb); resid (1,Eb)."""
-    scores = logpsi_ref[...] + pre_ref[...][:, None, :]      # (S,S,Eb)
-    m = jnp.maximum(jnp.max(scores, axis=0), NEG_INF)        # (S,Eb) over xi
-    s = jnp.sum(jnp.exp(scores - m[None, :, :]), axis=0)
-    cand = m + jnp.log(jnp.maximum(s, 1e-38))                # (S,Eb) [xj,e]
+    """Blocks: logpsi (S,S,Eb) [xi,xj,e]; pre/logm/dmask/out (S,Eb); resid (1,Eb).
+
+    The LSE over source states xi runs as two loops over xi (max, then the
+    shifted exp-sum), each on one (S, Eb) slice of the pairwise block: the
+    f32 ``exp`` of ``repro.kernels.explog`` keeps several temporaries per
+    element, which at (S, S, Eb) would not fit in VMEM."""
+    n_src = logpsi_ref.shape[0]
+
+    def scores(xi):                                          # (S,Eb) [xj,e]
+        return logpsi_ref[xi] + pre_ref[pl.ds(xi, 1), :]
+
+    m = jax.lax.fori_loop(1, n_src, lambda xi, m: jnp.maximum(m, scores(xi)),
+                          scores(0))
+    m = jnp.maximum(m, NEG_INF)
+    s = jax.lax.fori_loop(0, n_src,
+                          lambda xi, s: s + exp(scores(xi) - m),
+                          jnp.zeros_like(m))
+    cand = m + log(s)                                        # (S,Eb) [xj,e]
     dmask = dmask_ref[...] != 0
     cand = jnp.where(dmask, cand, NEG_INF)
     # renormalize over valid destination states (sublane reduction)
     zm = jnp.maximum(jnp.max(cand, axis=0), NEG_INF)         # (Eb,)
-    zs = jnp.sum(jnp.where(dmask, jnp.exp(cand - zm[None, :]), 0.0), axis=0)
-    z = zm + jnp.log(jnp.maximum(zs, 1e-38))
+    zs = jnp.sum(jnp.where(dmask, exp(cand - zm[None, :]), 0.0), axis=0)
+    z = zm + log(zs)
     new = jnp.where(dmask, cand - z[None, :], NEG_INF)
     out_ref[...] = new
     resid_ref[...] = jnp.max(

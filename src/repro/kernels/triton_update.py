@@ -59,6 +59,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.explog import exp, log
+
 try:  # GPU lowering knobs; absent/renamed on CPU-only or old installs.
     from jax.experimental.pallas import triton as plgpu
     _TRITON_PARAMS = getattr(plgpu, "TritonCompilerParams",
@@ -110,13 +112,13 @@ def _sum_kernel(logpsi_ref, pre_ref, logm_ref, dmask_ref, out_ref, resid_ref):
     """
     scores = logpsi_ref[...] + pre_ref[...][:, :, None]      # (Eb,S,S)
     m = jnp.maximum(jnp.max(scores, axis=1), NEG_INF)        # (Eb,S) over xi
-    s = jnp.sum(jnp.exp(scores - m[:, None, :]), axis=1)
-    cand = m + jnp.log(jnp.maximum(s, 1e-38))                # (Eb,S) [e,xj]
+    s = jnp.sum(exp(scores - m[:, None, :]), axis=1)
+    cand = m + log(s)                                        # (Eb,S) [e,xj]
     dmask = dmask_ref[...] != 0
     cand = jnp.where(dmask, cand, NEG_INF)
     zm = jnp.maximum(jnp.max(cand, axis=1), NEG_INF)         # (Eb,)
-    zs = jnp.sum(jnp.where(dmask, jnp.exp(cand - zm[:, None]), 0.0), axis=1)
-    z = zm + jnp.log(jnp.maximum(zs, 1e-38))
+    zs = jnp.sum(jnp.where(dmask, exp(cand - zm[:, None]), 0.0), axis=1)
+    z = zm + log(zs)
     new = jnp.where(dmask, cand - z[:, None], NEG_INF)
     out_ref[...] = new
     resid_ref[...] = jnp.max(

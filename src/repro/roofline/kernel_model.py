@@ -10,13 +10,25 @@ Per edge of S (padded) states at ``itemsize`` b:
 
 Flops are hand-counted from the kernel body, one flop per output element
 per arithmetic op (the same convention ``repro.roofline.jaxpr_cost``
-uses), so the jaxpr walker and this model are directly comparable:
+uses), so the jaxpr walker and this model are directly comparable. Both
+kernels take ``exp`` and ``log`` from ``repro.kernels.explog``: 31 and 41
+ops per element.
 
-    sum-product:  scores add S^2, src max-reduce S^2, shift-sub S^2,
-                  exp S^2, sum-reduce S^2                    -> 5*S^2
-                  + normalize/residual/mask tail              ~ 24*S + 6
-    max-product:  scores add S^2, src max-reduce S^2          -> 2*S^2
-                  + normalize/residual/mask tail              ~ 14*S + 1
+    GPU sum-product:  scores add S^2, src max-reduce S^2, shift-sub S^2,
+                      exp 31*S^2, sum-reduce S^2             -> 35*S^2
+                      + tail with S exps, S + 1 logs          ~ 93*S + 45
+    GPU max-product:  scores add S^2, src max-reduce S^2      -> 2*S^2
+                      + normalize/residual/mask tail          ~ 14*S + 1
+    TPU sum-product (``kernel="tpu"``), the LSE as two loops over source
+    states:
+        loop 1: scores add, max                               -> 2*S^2
+        loop 2: scores add, shift-sub, exp (31), sum          -> 34*S^2
+        + tail with S exps, S + 1 logs                        ~ 91*S + 48
+
+The exp/log ops run on the vector units, so the ridge point that decides
+whether a kernel is memory-bound is the vector f32 rate over memory
+bandwidth, not a matrix unit's peak; which side either kernel sits on is
+not measured.
 
 The O(S) tail constants are fitted once against the traced kernel (exact
 at time of writing); ``tests/test_roofline.py`` pins model-vs-jaxpr
@@ -33,9 +45,10 @@ __all__ = ["fused_update_cost", "predicted_intensity", "gpu_padded_shape",
            "round_cost"]
 
 _FLOPS_PER_EDGE = {
-    # semiring -> (S^2 coefficient, S coefficient, constant)
-    "sum": (5.0, 24.0, 6.0),
-    "max": (2.0, 14.0, 1.0),
+    # (kernel, semiring) -> (S^2 coefficient, S coefficient, constant)
+    ("gpu", "sum"): (35.0, 93.0, 45.0),
+    ("gpu", "max"): (2.0, 14.0, 1.0),
+    ("tpu", "sum"): (36.0, 91.0, 48.0),
 }
 
 
@@ -59,18 +72,20 @@ def gpu_padded_shape(n_edges: int, n_states: int, dtype_bytes: int = 4, *,
 
 
 def fused_update_cost(n_edges: int, n_states: int, *, dtype_bytes: int = 4,
-                      semiring: str = "sum", padded: bool = False) -> Cost:
+                      semiring: str = "sum", padded: bool = False,
+                      kernel: str = "gpu") -> Cost:
     """3-read/2-write model cost of one fused update over ``n_edges`` edges
-    of ``n_states`` states. With ``padded=True`` the GPU kernel's internal
-    padding (power-of-two states, block-multiple edges) is applied first,
-    predicting the *launched* cost rather than the logical one."""
-    if semiring not in _FLOPS_PER_EDGE:
-        raise ValueError(f"unknown semiring {semiring!r}; "
+    of ``n_states`` states by ``kernel`` ("gpu" | "tpu"; the byte model is
+    the same, the flops are not). With ``padded=True`` the GPU kernel's
+    internal padding (power-of-two states, block-multiple edges) is applied
+    first, predicting the *launched* cost rather than the logical one."""
+    if (kernel, semiring) not in _FLOPS_PER_EDGE:
+        raise ValueError(f"unknown kernel/semiring {(kernel, semiring)!r}; "
                          f"expected one of {sorted(_FLOPS_PER_EDGE)}")
     e, s = int(n_edges), int(n_states)
     if padded:
         e, s, _ = gpu_padded_shape(e, s, dtype_bytes)
-    a, b, c = _FLOPS_PER_EDGE[semiring]
+    a, b, c = _FLOPS_PER_EDGE[kernel, semiring]
     flops = e * (a * s * s + b * s + c)
     byts = e * ((s * s + 3 * s + 1) * dtype_bytes + s)
     return Cost(float(flops), float(byts))
@@ -80,10 +95,9 @@ def predicted_intensity(n_states: int, *, dtype_bytes: int = 4,
                         semiring: str = "sum", padded: bool = False) -> float:
     """Model arithmetic intensity (flops/byte) of the fused update; edge
     count cancels, so this is a pure function of the state count and width.
-    The roofline ridge point (peak_flops / hbm_bw, ~240 f/B on a v5e,
-    ~295 f/B on an H100) is far above every BP state count -- the update is
-    memory-bound everywhere, which is why the 3-read/2-write fusion (vs the
-    reference path's three separate round trips) is the whole win."""
+    Against a matrix unit's ridge (~240 f/B on a v5e) every BP state count
+    is memory-bound; against the vector units, which run this kernel's
+    exp/log, that is not measured (see the module docstring)."""
     c = fused_update_cost(1 if not padded else 64, n_states,
                           dtype_bytes=dtype_bytes, semiring=semiring,
                           padded=padded)

@@ -28,9 +28,8 @@ def run_on_cpu_devices():
     process may hold."""
 
     def run(code: str, n_devices: int = 8, timeout: int = 900) -> None:
-        env = dict(os.environ, PYTHONPATH=SRC, JAX_PLATFORMS="cpu",
-                   XLA_FLAGS="--xla_force_host_platform_device_count="
-                             f"{n_devices}")
+        from repro.dist import cpu_child_env
+        env = dict(cpu_child_env(n_devices), PYTHONPATH=SRC)
         out = subprocess.run([sys.executable, "-c", code], env=env,
                              capture_output=True, text=True, timeout=timeout)
         assert out.returncode == 0, out.stderr[-2000:]

@@ -26,7 +26,7 @@ except ImportError:                       # degrade: property tests skip
 from repro.core import messages as M
 from repro.core.graph import NEG_INF
 from repro.kernels.message_update import fused_update_t, pick_block_edges
-from repro.kernels import ops
+from repro.kernels import explog, ops
 from repro.kernels.ops import get_update_fn, make_pallas_update, pallas_update
 from repro.kernels.ref import fused_update_t_ref
 from repro.pgm import ising_grid, protein_like_graph
@@ -162,6 +162,39 @@ class TestInterpretRule:
             get_update_fn(other, batched=True)
         # an explicit interpret= still wins
         assert callable(get_update_fn(other, interpret=True))
+
+
+class TestExpLog:
+    """``repro.kernels.explog`` against float64 numpy: within about an ulp
+    on the ranges BP feeds it (the TPU's own f32 exp/log are not)."""
+
+    @pytest.mark.parametrize("fn,ref,lo,hi,log_scale", [
+        (explog.exp, np.exp, -87.0, 0.0, False),  # LSE shifts: x - max <= 0
+        (explog.exp, np.exp, 0.0, 88.0, False),
+        (explog.log, np.log, 1.0, 128.0, False),  # sums of <= S terms >= 1
+        (explog.log, np.log, -37.0, 0.0, True),   # 1e-37 .. 1
+    ])
+    def test_within_an_ulp(self, rng, fn, ref, lo, hi, log_scale):
+        x = rng.uniform(lo, hi, size=1 << 16)
+        x = (10.0 ** x if log_scale else x).astype(np.float32)
+        got = np.asarray(jax.jit(fn)(x), np.float64)
+        want = ref(x.astype(np.float64))
+        ulp = np.spacing(np.abs(want).astype(np.float32)).astype(np.float64)
+        assert np.max(np.abs(got - want) / ulp) <= 1.5
+
+    def test_domain_edges(self):
+        e = np.asarray(explog.exp(jnp.array([NEG_INF, -88.0, 0.0],
+                                            jnp.float32)))
+        assert e[0] == 0.0 and e[1] == 0.0 and e[2] == 1.0
+        lg = np.asarray(explog.log(jnp.array([0.0, 1e-40, 1.0], jnp.float32)))
+        floor = np.log(np.float32(np.finfo(np.float32).tiny))
+        np.testing.assert_allclose(lg[:2], floor, rtol=1e-6)
+        assert lg[2] == 0.0
+
+    def test_keeps_dtype(self):
+        x = jnp.linspace(-4.0, 0.0, 64, dtype=jnp.bfloat16)
+        assert explog.exp(x).dtype == jnp.bfloat16
+        assert explog.log(-x + 1).dtype == jnp.bfloat16
 
 
 class TestKernelProperties:

@@ -45,6 +45,7 @@ from typing import Any, Callable, List, Mapping, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core import messages as M
 from repro.core.batch import BatchedPGM, batch_keys, bucket_pgms
@@ -234,26 +235,33 @@ def _chunk_single(pgm: PGM, carry, limit, eps, *, scheduler: Scheduler,
 
     def body(c):
         logm, sstate, rng, rounds, done, updates, hist, _, iters = c
-        rng, sel_key = jax.random.split(rng)
+        with jax.named_scope("bp.select"):
+            rng, sel_key = jax.random.split(rng)
         cand, r = update_fn(pgm, logm)
-        unconverged = jnp.sum((r >= eps) & pgm.edge_mask).astype(jnp.int32)
-        frontier, sstate = scheduler.select(pgm, r, eps, sel_key, sstate,
-                                            unconverged)
-        # Converged -> commit nothing (IsConverged precedes Update in Alg. 1).
-        newly_done = unconverged == 0
-        frontier = frontier & ~newly_done
-        logm = M.apply_frontier(logm, cand, frontier, damping)
+        with jax.named_scope("bp.select"):
+            unconverged = jnp.sum((r >= eps)
+                                  & pgm.edge_mask).astype(jnp.int32)
+            frontier, sstate = scheduler.select(pgm, r, eps, sel_key, sstate,
+                                                unconverged)
+        with jax.named_scope("bp.commit"):
+            # Converged -> commit nothing (IsConverged precedes Update in
+            # Alg. 1).
+            newly_done = unconverged == 0
+            frontier = frontier & ~newly_done
+            logm = M.apply_frontier(logm, cand, frontier, damping)
         # Residual Splash: h-1 extra masked sweeps inside the same frontier.
         for _ in range(scheduler.inner_sweeps - 1):
             cand, _ = update_fn(pgm, logm)
-            logm = M.apply_frontier(logm, cand, frontier, damping)
-        updates = updates + jnp.sum(frontier).astype(jnp.uint32) \
-            * jnp.uint32(scheduler.inner_sweeps)
-        if track_history:
-            hist = hist.at[rounds].set(unconverged)
-        rounds = rounds + jnp.where(newly_done, 0,
-                                    jnp.int32(scheduler.inner_sweeps))
-        max_r = jnp.max(r)
+            with jax.named_scope("bp.commit"):
+                logm = M.apply_frontier(logm, cand, frontier, damping)
+        with jax.named_scope("bp.commit"):
+            updates = updates + jnp.sum(frontier).astype(jnp.uint32) \
+                * jnp.uint32(scheduler.inner_sweeps)
+            if track_history:
+                hist = hist.at[rounds].set(unconverged)
+            rounds = rounds + jnp.where(newly_done, 0,
+                                        jnp.int32(scheduler.inner_sweeps))
+            max_r = jnp.max(r)
         return (logm, sstate, rng, rounds, newly_done, updates, hist, max_r,
                 iters + 1)
 
@@ -302,30 +310,36 @@ def _chunk_batch(batch: BatchedPGM, carry, limit, eps, *,
 
     def body(c):
         logm, sstate, keys, rounds, done, updates, hist, _, iters = c
-        active = (~done) & (rounds < limit)                     # (B,)
-        split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-        keys = _where_keys(active, split[:, 0], keys)
-        sel_keys = split[:, 1]
+        with jax.named_scope("bp.select"):
+            active = (~done) & (rounds < limit)                 # (B,)
+            split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+            keys = _where_keys(active, split[:, 0], keys)
+            sel_keys = split[:, 1]
         cand, r = batch_update_fn(bpgm, logm)
-        unconverged = jnp.sum((r >= eps) & bpgm.edge_mask,
-                              axis=1).astype(jnp.int32)         # (B,)
-        frontier, new_sstate = select(bpgm, r, sel_keys, sstate, unconverged)
-        sstate = jax.tree.map(partial(_bcast_where, active),
-                              new_sstate, sstate)
-        newly_done = (unconverged == 0) & active
-        frontier = frontier & active[:, None] & ~newly_done[:, None]
-        logm = commit(logm, cand, frontier)
+        with jax.named_scope("bp.select"):
+            unconverged = jnp.sum((r >= eps) & bpgm.edge_mask,
+                                  axis=1).astype(jnp.int32)     # (B,)
+            frontier, new_sstate = select(bpgm, r, sel_keys, sstate,
+                                          unconverged)
+            sstate = jax.tree.map(partial(_bcast_where, active),
+                                  new_sstate, sstate)
+        with jax.named_scope("bp.commit"):
+            newly_done = (unconverged == 0) & active
+            frontier = frontier & active[:, None] & ~newly_done[:, None]
+            logm = commit(logm, cand, frontier)
         for _ in range(scheduler.inner_sweeps - 1):
             cand, _ = batch_update_fn(bpgm, logm)
-            logm = commit(logm, cand, frontier)
-        updates = updates + jnp.sum(frontier, axis=1).astype(jnp.uint32) \
-            * jnp.uint32(scheduler.inner_sweeps)
-        if track_history:
-            hist = jax.vmap(lambda h, i, u, a: jnp.where(
-                a, h.at[i].set(u), h))(hist, rounds, unconverged, active)
-        rounds = rounds + jnp.where(newly_done | ~active, 0,
-                                    jnp.int32(scheduler.inner_sweeps))
-        max_r = jnp.max(r, axis=1)
+            with jax.named_scope("bp.commit"):
+                logm = commit(logm, cand, frontier)
+        with jax.named_scope("bp.commit"):
+            updates = updates + jnp.sum(frontier, axis=1).astype(jnp.uint32) \
+                * jnp.uint32(scheduler.inner_sweeps)
+            if track_history:
+                hist = jax.vmap(lambda h, i, u, a: jnp.where(
+                    a, h.at[i].set(u), h))(hist, rounds, unconverged, active)
+            rounds = rounds + jnp.where(newly_done | ~active, 0,
+                                        jnp.int32(scheduler.inner_sweeps))
+            max_r = jnp.max(r, axis=1)
         return (logm, sstate, keys, rounds, done | newly_done, updates, hist,
                 max_r, iters + 1)
 
@@ -520,18 +534,29 @@ class BPEngine:
         (same trajectory, checkpointable); otherwise one ``while_loop``.
         ``state`` resumes an existing trajectory instead of starting fresh.
         For ``scheduler='srbp'`` runs the host-serial baseline and returns an
-        ``SRBPResult``."""
-        if self.is_serial:
-            from repro.core.serial import srbp_run
-            kw = dict(self.config.scheduler_kwargs)
-            return srbp_run(graph, eps=self.config.eps, **kw)
-        if state is None:
-            if rng is None:
-                raise ValueError("run() needs an rng key (or a state)")
-            state = self.init(graph, rng)
-        while not self.finished(state):
-            state = self.step(state)
-        return self.result(state)
+        ``SRBPResult``.
+
+        Under an active ``jax.profiler`` trace the call is a ``bp.run`` span
+        on the calling thread, with ``bp.init``, ``bp.step`` (dispatch),
+        ``bp.finished`` (the blocking sync) and ``bp.result`` in it."""
+        with TraceAnnotation("bp.run"):
+            if self.is_serial:
+                from repro.core.serial import srbp_run
+                kw = dict(self.config.scheduler_kwargs)
+                return srbp_run(graph, eps=self.config.eps, **kw)
+            if state is None:
+                if rng is None:
+                    raise ValueError("run() needs an rng key (or a state)")
+                with TraceAnnotation("bp.init"):
+                    state = self.init(graph, rng)
+            while True:
+                with TraceAnnotation("bp.finished"):
+                    if self.finished(state):
+                        break
+                with TraceAnnotation("bp.step"):
+                    state = self.step(state)
+            with TraceAnnotation("bp.result"):
+                return self.result(state)
 
     def run_many(self, pgms: Sequence[PGM], rng: jax.Array, *,
                  growth: float = 2.0,

@@ -21,7 +21,8 @@ passed through ``jax.jit`` / ``shard_map`` unchanged.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+import time
+from functools import partial, wraps
 from typing import Sequence
 
 import jax
@@ -38,8 +39,25 @@ EDGE_PAD = 128
 VERTEX_PAD = 8
 
 
+#: ``jax.monitoring`` event: seconds each ``build_pgm``/``build_pgm_uniform``
+#: call took (host work and the dispatch of its device copies).
+BUILD_EVENT = "/repro/pgm/build_duration"
+
+
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def _records_build(build):
+    """Record each call's duration as ``BUILD_EVENT``."""
+    @wraps(build)
+    def timed(*args, **kwargs):
+        t0 = time.perf_counter()
+        pgm = build(*args, **kwargs)
+        jax.monitoring.record_event_duration_secs(
+            BUILD_EVENT, time.perf_counter() - t0)
+        return pgm
+    return timed
 
 
 @jax.tree_util.register_dataclass
@@ -110,6 +128,7 @@ class PGM:
             num_segments=self.n_vertices)
 
 
+@_records_build
 def build_pgm_uniform(
     n_vertices: int,
     edges: np.ndarray,          # (E_und, 2)
@@ -164,6 +183,7 @@ def build_pgm_uniform(
         edge_count=jnp.int32(e_dir), vertex_count=jnp.int32(n_vertices))
 
 
+@_records_build
 def build_pgm(
     n_vertices: int,
     edges: np.ndarray,              # (E_und, 2) int, undirected vertex pairs

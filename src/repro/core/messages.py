@@ -57,14 +57,16 @@ def vertex_logprod(pgm: PGM, logm: jax.Array) -> jax.Array:
 
 def edge_prelude(pgm: PGM, logm: jax.Array,
                  vsum: jax.Array | None = None) -> jax.Array:
-    """(E, S) per-edge source-side belief excluding the reverse message."""
-    if vsum is None:
-        vsum = vertex_logprod(pgm, logm)
-    pre = (pgm.log_psi_v[pgm.edge_src]
-           + vsum[pgm.edge_src]
-           - logm[pgm.edge_rev])
-    src_mask = pgm.state_mask[pgm.edge_src]
-    return jnp.where(src_mask, pre, NEG_INF)
+    """(E, S) per-edge source-side belief excluding the reverse message.
+    Its ops carry the ``bp.prelude`` scope in traces."""
+    with jax.named_scope("bp.prelude"):
+        if vsum is None:
+            vsum = vertex_logprod(pgm, logm)
+        pre = (pgm.log_psi_v[pgm.edge_src]
+               + vsum[pgm.edge_src]
+               - logm[pgm.edge_rev])
+        src_mask = pgm.state_mask[pgm.edge_src]
+        return jnp.where(src_mask, pre, NEG_INF)
 
 
 def propagate_ref(log_psi_e: jax.Array, pre: jax.Array) -> jax.Array:
@@ -130,9 +132,10 @@ def ref_update(pgm: PGM, logm: jax.Array):
     """One fused BP step: (candidate messages, residuals). Pure-jnp reference;
     the Pallas path (repro.kernels.ops.pallas_update) matches this signature."""
     pre = edge_prelude(pgm, logm)
-    cand = propagate_ref(pgm.log_psi_e, pre)
-    return normalize_and_residual(cand, logm, pgm.state_mask[pgm.edge_dst],
-                                  pgm.edge_mask)
+    with jax.named_scope("bp.update"):
+        cand = propagate_ref(pgm.log_psi_e, pre)
+        return normalize_and_residual(
+            cand, logm, pgm.state_mask[pgm.edge_dst], pgm.edge_mask)
 
 
 # ------------------------------------------------------ max-product (MAP) --

@@ -98,21 +98,24 @@ def fused_update_t(logpsi_t: jax.Array,   # (S, S, E) [x_src, x_dst, e]
                    dmask_t: jax.Array,    # (S, E) int8/bool valid dst states
                    *, interpret: bool = False):
     """Returns (new_logm_t (S, E), residual (E,)). Edges are padded to the
-    block size internally (padded lanes carry all-masked states -> inert)."""
+    block size internally (padded lanes carry all-masked states -> inert).
+    In traces the padding and slicing carry the ``bp.layout`` scope."""
     s, e = pre_t.shape
     # Size blocks for the *actual* operand width: bf16 operands halve the
     # per-edge working set, so the VMEM budget admits twice the edges.
     blk = min(pick_block_edges(s, jnp.dtype(pre_t.dtype).itemsize),
               max(_LANE, e))
     e_pad = ((e + blk - 1) // blk) * blk
-    if e_pad != e:
-        pad = [(0, 0)] * (len(logpsi_t.shape) - 1) + [(0, e_pad - e)]
-        logpsi_t = jnp.pad(logpsi_t, pad)
-        pre_t = jnp.pad(pre_t, ((0, 0), (0, e_pad - e)),
-                        constant_values=NEG_INF)
-        logm_t = jnp.pad(logm_t, ((0, 0), (0, e_pad - e)),
-                         constant_values=NEG_INF)
-        dmask_t = jnp.pad(dmask_t, ((0, 0), (0, e_pad - e)))
+    with jax.named_scope("bp.layout"):
+        if e_pad != e:
+            pad = [(0, 0)] * (len(logpsi_t.shape) - 1) + [(0, e_pad - e)]
+            logpsi_t = jnp.pad(logpsi_t, pad)
+            pre_t = jnp.pad(pre_t, ((0, 0), (0, e_pad - e)),
+                            constant_values=NEG_INF)
+            logm_t = jnp.pad(logm_t, ((0, 0), (0, e_pad - e)),
+                             constant_values=NEG_INF)
+            dmask_t = jnp.pad(dmask_t, ((0, 0), (0, e_pad - e)))
+        dmask_t = dmask_t.astype(jnp.int8)
     grid = (e_pad // blk,)
     new_t, resid = pl.pallas_call(
         _fused_kernel,
@@ -132,5 +135,6 @@ def fused_update_t(logpsi_t: jax.Array,   # (S, S, E) [x_src, x_dst, e]
             jax.ShapeDtypeStruct((1, e_pad), pre_t.dtype),
         ],
         interpret=interpret,
-    )(logpsi_t, pre_t, logm_t, dmask_t.astype(jnp.int8))
-    return new_t[:, :e], resid[0, :e]
+    )(logpsi_t, pre_t, logm_t, dmask_t)
+    with jax.named_scope("bp.layout"):
+        return new_t[:, :e], resid[0, :e]

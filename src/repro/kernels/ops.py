@@ -76,9 +76,10 @@ def _resolve_interpret(backend: str, interpret: bool | None) -> bool:
 
 def kernel_operands_t(pgm: PGM):
     """Precompute the static transposed operands (do once per graph)."""
-    logpsi_t = jnp.transpose(pgm.log_psi_e, (1, 2, 0))      # (S, S, E)
-    dmask_t = pgm.state_mask[pgm.edge_dst].T                # (S, E)
-    return logpsi_t, dmask_t
+    with jax.named_scope("bp.layout"):
+        logpsi_t = jnp.transpose(pgm.log_psi_e, (1, 2, 0))  # (S, S, E)
+        dmask_t = pgm.state_mask[pgm.edge_dst].T            # (S, E)
+        return logpsi_t, dmask_t
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -87,9 +88,13 @@ def pallas_update(pgm: PGM, logm: jax.Array, *, interpret: bool | None = None):
     interpret = _resolve_interpret("pallas", interpret)
     pre = M.edge_prelude(pgm, logm)                          # (E, S)
     logpsi_t, dmask_t = kernel_operands_t(pgm)
-    new_t, resid = fused_update_t(
-        logpsi_t, pre.T, logm.T, dmask_t, interpret=interpret)
-    return new_t.T, resid
+    with jax.named_scope("bp.layout"):
+        pre_t, logm_t = pre.T, logm.T
+    with jax.named_scope("bp.update"):
+        new_t, resid = fused_update_t(
+            logpsi_t, pre_t, logm_t, dmask_t, interpret=interpret)
+    with jax.named_scope("bp.layout"):
+        return new_t.T, resid
 
 
 def make_pallas_update(interpret: bool | None = None):
@@ -112,14 +117,19 @@ def pallas_update_batch(bpgm: PGM, logm: jax.Array, *,
     interpret = _resolve_interpret("pallas", interpret)
     b, e, s = logm.shape
     pre = jax.vmap(M.edge_prelude)(bpgm, logm)                # (B, E, S)
-    # Fold batch into edges: graph b's edge e becomes folded edge b*E + e.
-    logpsi_t = jnp.transpose(bpgm.log_psi_e.reshape(b * e, s, s), (1, 2, 0))
-    dmask = jax.vmap(lambda p: p.state_mask[p.edge_dst])(bpgm)
-    dmask_t = dmask.reshape(b * e, s).T                       # (S, B*E)
-    new_t, resid = fused_update_t(
-        logpsi_t, pre.reshape(b * e, s).T, logm.reshape(b * e, s).T,
-        dmask_t, interpret=interpret)
-    return new_t.T.reshape(b, e, s), resid.reshape(b, e)
+    with jax.named_scope("bp.layout"):
+        # Fold batch into edges: graph b's edge e becomes folded edge
+        # b*E + e.
+        logpsi_t = jnp.transpose(bpgm.log_psi_e.reshape(b * e, s, s),
+                                 (1, 2, 0))
+        dmask = jax.vmap(lambda p: p.state_mask[p.edge_dst])(bpgm)
+        dmask_t = dmask.reshape(b * e, s).T                   # (S, B*E)
+        pre_t, logm_t = pre.reshape(b * e, s).T, logm.reshape(b * e, s).T
+    with jax.named_scope("bp.update"):
+        new_t, resid = fused_update_t(logpsi_t, pre_t, logm_t, dmask_t,
+                                      interpret=interpret)
+    with jax.named_scope("bp.layout"):
+        return new_t.T.reshape(b, e, s), resid.reshape(b, e)
 
 
 def make_pallas_update_batch(interpret: bool | None = None):

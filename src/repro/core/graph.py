@@ -43,6 +43,23 @@ VERTEX_PAD = 8
 #: call took (host work and the dispatch of its device copies).
 BUILD_EVENT = "/repro/pgm/build_duration"
 
+#: ``jax.monitoring`` scalar: each build's in-edge table width D (the
+#: largest real in-degree), or 0 where the builder left the table out.
+IN_EDGE_WIDTH = "/repro/pgm/in_edge_width"
+
+#: The builders keep a (V, D) in-edge table only where it is compact:
+#: V * D <= IN_EDGE_SLACK * (real directed edges). On TPU v5e a scatter-add
+#: costs about 9 ns per edge and a gather about 1.3-1.4 ns per row (Ising
+#: 200x200 round loop), so summing by D gathers of V rows wins while the
+#: table holds up to about 6x the real edges; the factor 2 keeps well inside
+#: that. Grids, chains and regular LDPC codes pass; hub-heavy graphs (stars,
+#: skewed contact maps) keep the scatter-add.
+IN_EDGE_SLACK = 2
+
+#: Unused in-edge table slots: out of range for any edge axis, so the
+#: fill-mode gather reads them as 0.
+IN_EDGE_FILL = np.iinfo(np.int32).max
+
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
@@ -73,6 +90,12 @@ class PGM:
       log_psi_v                    : (V, S) f32     NEG_INF at invalid states
       state_mask                   : (V, S) bool
       n_states                     : (V,)  int32
+      in_edges                     : (V, D) int32 or None. Row v lists the
+          real edges into v in ascending edge id, then ``IN_EDGE_FILL``;
+          D is the largest real in-degree. Only the builders fill it, and
+          only where it is compact (``IN_EDGE_SLACK``); every re-padded,
+          stacked, folded or sharded PGM carries None and sums by
+          ``segment_sum``.
     """
 
     edge_src: jax.Array
@@ -94,6 +117,7 @@ class PGM:
     # static ints for hand-built PGMs.
     edge_count: jax.Array | None = None
     vertex_count: jax.Array | None = None
+    in_edges: jax.Array | None = None
 
     @property
     def n_edges(self) -> int:
@@ -126,6 +150,27 @@ class PGM:
         return jax.ops.segment_sum(
             self.edge_mask.astype(jnp.int32), self.edge_dst,
             num_segments=self.n_vertices)
+
+
+def in_edge_table(edge_dst: np.ndarray, edge_mask: np.ndarray,
+                  n_vertices: int) -> jax.Array | None:
+    """(V, D) int32 table of each vertex's real in-edges, ascending, padded
+    with ``IN_EDGE_FILL``; None where it is not compact
+    (V * D > IN_EDGE_SLACK * real edges). Records ``IN_EDGE_WIDTH``."""
+    real = np.flatnonzero(edge_mask)
+    dst = edge_dst[real]
+    order = np.argsort(dst, kind="stable")
+    real, dst = real[order], dst[order]
+    counts = np.bincount(dst, minlength=n_vertices)
+    width = int(counts.max(initial=0))
+    if width == 0 or n_vertices * width > IN_EDGE_SLACK * real.size:
+        jax.monitoring.record_scalar(IN_EDGE_WIDTH, 0)
+        return None
+    first = np.cumsum(counts) - counts           # first sorted slot per vertex
+    table = np.full((n_vertices, width), IN_EDGE_FILL, dtype=np.int32)
+    table[dst, np.arange(real.size) - first[dst]] = real
+    jax.monitoring.record_scalar(IN_EDGE_WIDTH, width)
+    return jnp.asarray(table)
 
 
 @_records_build
@@ -180,7 +225,8 @@ def build_pgm_uniform(
         log_psi_v=jnp.asarray(log_psi_v, dtype=dtype),
         state_mask=jnp.asarray(state_mask), n_states=jnp.asarray(n_states),
         n_real_vertices=n_vertices, n_real_edges=e_dir,
-        edge_count=jnp.int32(e_dir), vertex_count=jnp.int32(n_vertices))
+        edge_count=jnp.int32(e_dir), vertex_count=jnp.int32(n_vertices),
+        in_edges=in_edge_table(edge_dst, edge_mask, v_pad))
 
 
 @_records_build
@@ -259,6 +305,7 @@ def build_pgm(
         n_real_edges=e_dir,
         edge_count=jnp.int32(e_dir),
         vertex_count=jnp.int32(n_vertices),
+        in_edges=in_edge_table(edge_dst, edge_mask, v_pad),
     )
 
 
@@ -315,7 +362,8 @@ def pad_pgm(pgm: PGM, *, n_edges: int, n_vertices: int, n_states: int,
     builders use, so BP on the padded graph commits the same messages on
     real edges. The optional ``n_real_*`` override the *static* metadata to
     a bucket ceiling (shared treedef across a batch); the traced per-graph
-    counts are preserved.
+    counts are preserved. The result has no in-edge table (``in_edges`` is
+    None): its sums run by ``segment_sum``.
     """
     arrs = pad_pgm_arrays(pgm, n_edges=n_edges, n_vertices=n_vertices,
                           n_states=n_states)

@@ -8,7 +8,8 @@ directed edges (static shapes; the scheduler masks which results commit):
 
 In log space with a per-vertex "incoming sum" cache:
 
-    vsum[i]   = sum over incoming edges e'=(k->i) of logm[e']        (segment_sum)
+    vsum[i]   = sum over incoming edges e'=(k->i) of logm[e']
+                (gathers over the builder's in-edge table, else segment_sum)
     pre[e]    = log_psi_v[src] + vsum[src] - logm[rev(e)]            (exclude j->i)
     cand[e,j] = LSE_{x_i}( log_psi_e[e, x_i, x_j] + pre[e, x_i] )    (hot spot)
 
@@ -45,14 +46,30 @@ def init_messages(pgm: PGM, dtype=jnp.float32) -> jax.Array:
     return logm.astype(dtype)
 
 
-def vertex_logprod(pgm: PGM, logm: jax.Array) -> jax.Array:
+def vertex_logprod(pgm: PGM, logm: jax.Array,
+                   onto: jax.Array | None = None) -> jax.Array:
     """(V, S) sum of incoming log-messages per vertex (the paper's per-vertex
-    message product, in log space). Padded edges target the dummy vertex so
-    they never pollute real sums; invalid states carry NEG_INF garbage which
-    downstream masking discards."""
+    message product, in log space), added onto ``onto`` (V, S) where given.
+    Padded edges target the dummy vertex so they never pollute real sums;
+    invalid states carry NEG_INF garbage which downstream masking discards.
+
+    With the builder's in-edge table (``pgm.in_edges``) the sum is D gathers
+    of V rows added in slot order (ascending edge id) after ``onto``: the
+    order in which XLA's scatter-add accumulates on the CPU, where it also
+    folds an added ``onto`` in as the scatter's initial value. Unused slots
+    read 0. Without the table (every re-padded, stacked, folded or sharded
+    PGM) it is a ``segment_sum``."""
+    if pgm.in_edges is not None:
+        total = onto
+        for k in range(pgm.in_edges.shape[1]):
+            part = logm.at[pgm.in_edges[:, k]].get(mode="fill",
+                                                   fill_value=0.0)
+            total = part if total is None else total + part
+        return total
     contrib = jnp.where(pgm.edge_mask[:, None], logm, 0.0)
-    return jax.ops.segment_sum(contrib, pgm.edge_dst,
-                               num_segments=pgm.n_vertices)
+    total = jax.ops.segment_sum(contrib, pgm.edge_dst,
+                                num_segments=pgm.n_vertices)
+    return total if onto is None else onto + total
 
 
 def edge_prelude(pgm: PGM, logm: jax.Array,
@@ -122,7 +139,7 @@ def residuals(pgm: PGM, logm: jax.Array, cand: jax.Array) -> jax.Array:
 
 def beliefs(pgm: PGM, logm: jax.Array) -> jax.Array:
     """(V, S) normalized log-marginals (paper Eq. 3)."""
-    b = pgm.log_psi_v + vertex_logprod(pgm, logm)
+    b = vertex_logprod(pgm, logm, onto=pgm.log_psi_v)
     z = masked_logsumexp(b, pgm.state_mask, axis=1)
     b = b - z[:, None]
     return jnp.where(pgm.state_mask, b, NEG_INF)
@@ -161,7 +178,7 @@ def max_product_update(pgm: PGM, logm: jax.Array):
 
 def map_assignment(pgm: PGM, logm: jax.Array) -> jax.Array:
     """(V,) argmax decoding of max-product beliefs."""
-    b = pgm.log_psi_v + vertex_logprod(pgm, logm)
+    b = vertex_logprod(pgm, logm, onto=pgm.log_psi_v)
     b = jnp.where(pgm.state_mask, b, NEG_INF)
     return jnp.argmax(b, axis=1)
 

@@ -106,7 +106,8 @@ def shard_pgm(pgm: PGM, mesh: Mesh, *, axis: str = BP_AXIS) -> PGM:
     ``axis``, vertex-axis leaves (``log_psi_v``/``state_mask``/``n_states``,
     all small) replicated. Shapes/dtypes are unchanged; only device layout
     moves. The padded edge count must divide the mesh size into even shards
-    (see ``run_bp_sharded``, which re-pads automatically)."""
+    (see ``run_bp_sharded``, which re-pads automatically). The in-edge
+    table is dropped (``in_edges=None``)."""
     _check_edge_layout(pgm, mesh.shape[axis])
     edge = NamedSharding(mesh, P(axis))
     edge3 = NamedSharding(mesh, P(axis, None, None))
@@ -126,7 +127,10 @@ def shard_pgm(pgm: PGM, mesh: Mesh, *, axis: str = BP_AXIS) -> PGM:
         edge_count=(None if pgm.edge_count is None
                     else jax.device_put(pgm.edge_count, rep)),
         vertex_count=(None if pgm.vertex_count is None
-                      else jax.device_put(pgm.vertex_count, rep)))
+                      else jax.device_put(pgm.vertex_count, rep)),
+        # The sharded update sums by its own segment_sum; a single-device
+        # in-edge table must not ride into the mesh-sharded jit.
+        in_edges=None)
 
 
 def make_sharded_update(mesh: Mesh | None = None, *, axis: str = BP_AXIS):

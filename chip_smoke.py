@@ -15,9 +15,12 @@ One chip runs the normal entry points (``BPConfig`` -> ``BPEngine`` ->
 2. paper scale: Ising 200x200 (the source paper's largest grid) with rnbp
    at C=2.5 and lbp at C=2.0 (where lbp converges), ``pallas`` against
    ``ref``;
-3. real size: the 288x384, 16-label stereo MRF (the Middlebury Tsukuba
-   pair's size) through ``engine.run``; its compiled chunk must contain the
-   kernel (``tpu_custom_call``), so a kernel that quietly interprets fails;
+3. real size: MAP stereo on a 288x384 pair with 16 labels (the Tsukuba
+   pair's size; Felzenszwalb & Huttenlocher's energy) through ``engine.run``
+   with the kernel's max-product body (``pallas_max``); its compiled chunk
+   must contain the kernel (``tpu_custom_call``), so a kernel that quietly
+   interprets fails; its messages must match ``maxprod``'s and its labeling
+   be no less accurate than the data term's own per-pixel argmax;
 4. serving: a mixed stream with a Tsukuba-size frame and two LDPC codewords
    (32-state check vertices: the kernel's widest block here) through
    ``serve_async``; every request must complete and converge.
@@ -221,35 +224,54 @@ def decoded(beliefs, n: int, s: int):
     return np.argmax(np.asarray(beliefs)[:n, :s], axis=1)
 
 
+#: pallas_max against maxprod: the same max-product ops, so the messages
+#: agree to rounding at most.
+MAX_MSG_ATOL = 1e-6
+
+
 def phase_stereo() -> None:
-    """Tsukuba-size stereo MRF through ``engine.run`` with the kernel."""
-    from repro.pgm import stereo_mrf
+    """Tsukuba-size MAP stereo through ``engine.run`` with the kernel's
+    max-product body, against the pure-jnp ``maxprod``."""
+    from repro.pgm import stereo_pair_mrf
 
     h, w, d = STEREO
-    inst, build = timed(lambda: stereo_mrf(h, w, d, seed=0))
-    log(f"stereo {h}x{w}x{d}: E={inst.pgm.n_real_edges} directed edges, "
-        f"tables {inst.pgm.log_psi_e.nbytes / 2**20:.0f} MiB, "
+    inst, build = timed(lambda: stereo_pair_mrf(h, w, d, seed=0))
+    log(f"stereo pair {h}x{w}x{d}: E={inst.pgm.n_real_edges} directed "
+        f"edges, tables {inst.pgm.log_psi_e.nbytes / 2**20:.0f} MiB, "
         f"built in {build:.2f}s")
-    eng = engine("pallas", "rnbp", 1e-3)
     key = jax.random.key(0)
-    t0 = time.perf_counter()
-    hlo = chunk_hlo(eng, eng.init(inst.pgm, key))
-    log(f"stereo chunk compiled in {time.perf_counter() - t0:.1f}s; "
-        f"tpu_custom_call in HLO: {'tpu_custom_call' in hlo}")
-    check("tpu_custom_call" in hlo,
-          "the pallas chunk's HLO has no tpu_custom_call: the kernel is "
-          "not compiled for the chip")
-    res, wall = timed(lambda: eng.run(inst.pgm, key))
-    labels = decoded(res.beliefs, h * w, d)
-    obs = np.clip(np.round(inst.obs), 0, d - 1).astype(int)
-    acc_bp, acc_obs = inst.accuracy(labels), inst.accuracy(obs)
-    e_bp, e_truth = inst.energy(labels), inst.energy(inst.truth)
-    log(f"stereo {h}x{w}x{d} rnbp pallas: rounds={int(res.rounds)} "
-        f"converged={bool(res.converged)} wall_cold={wall:.3f}s "
-        f"acc_bp={acc_bp:.4f} acc_obs={acc_obs:.4f} energy_bp={e_bp:.2f} "
-        f"energy_truth={e_truth:.2f}")
-    check(bool(res.converged), "stereo did not converge")
-    check(acc_bp >= acc_obs, "stereo BP is less accurate than its input")
+    res = {}
+    for backend in ("pallas_max", "maxprod"):
+        eng = engine(backend, "rnbp", 1e-3)
+        if backend == "pallas_max":
+            t0 = time.perf_counter()
+            hlo = chunk_hlo(eng, eng.init(inst.pgm, key))
+            log(f"stereo chunk compiled in {time.perf_counter() - t0:.1f}s; "
+                f"tpu_custom_call in HLO: {'tpu_custom_call' in hlo}")
+            check("tpu_custom_call" in hlo,
+                  "the pallas_max chunk's HLO has no tpu_custom_call: the "
+                  "kernel is not compiled for the chip")
+        res[backend], wall = timed(lambda: eng.run(inst.pgm, key))
+        r = res[backend]
+        labels = decoded(r.beliefs, h * w, d)
+        log(f"stereo {h}x{w}x{d} rnbp {backend}: rounds={int(r.rounds)} "
+            f"converged={bool(r.converged)} wall_cold={wall:.3f}s "
+            f"acc_bp={inst.accuracy(labels):.4f} "
+            f"energy_bp={inst.energy(labels):.2f} "
+            f"energy_truth={inst.energy(inst.truth):.2f}")
+        check(bool(r.converged), f"stereo {backend} did not converge")
+    got, want = res["pallas_max"], res["maxprod"]
+    dst_mask = np.asarray(inst.pgm.state_mask)[np.asarray(inst.pgm.edge_dst)]
+    diff = max_belief_diff(got.logm, want.logm, dst_mask)
+    acc_bp = inst.accuracy(decoded(got.beliefs, h * w, d))
+    acc_wta = inst.accuracy(inst.winner_take_all())
+    log(f"stereo pallas_max vs maxprod: messages within {diff:.3g}, rounds "
+        f"{int(got.rounds)} vs {int(want.rounds)}; acc_bp={acc_bp:.4f} "
+        f"acc_wta={acc_wta:.4f}")
+    check(diff <= MAX_MSG_ATOL,
+          f"stereo pallas_max messages off maxprod's by {diff}")
+    check(acc_bp >= acc_wta,
+          "stereo MAP is less accurate than the data term's own argmax")
 
 
 def phase_serving() -> None:

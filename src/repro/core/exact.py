@@ -19,10 +19,11 @@ def _logsumexp(a: np.ndarray, axis=None) -> np.ndarray:
     return out
 
 
-def brute_force_marginals(n_vertices: int, edges: np.ndarray,
-                          unary: Sequence[np.ndarray],
-                          pairwise: Sequence[np.ndarray]) -> List[np.ndarray]:
-    """Enumerate the full joint. Only for tiny graphs (prod of states <~ 1e7)."""
+def _log_joint(n_vertices: int, edges: np.ndarray,
+               unary: Sequence[np.ndarray],
+               pairwise: Sequence[np.ndarray]) -> np.ndarray:
+    """The unnormalized log joint over every assignment, one axis per
+    vertex. Only for tiny graphs (prod of states <~ 1e7)."""
     sizes = [len(u) for u in unary]
     total = int(np.prod(sizes))
     assert total <= 10_000_000, "graph too large for brute force"
@@ -38,6 +39,14 @@ def brute_force_marginals(n_vertices: int, edges: np.ndarray,
             table.reshape([sizes[i], sizes[j]] + [1] * (n_vertices - 2)),
             [0, 1], [i, j])
         log_joint = log_joint + reshaped
+    return log_joint
+
+
+def brute_force_marginals(n_vertices: int, edges: np.ndarray,
+                          unary: Sequence[np.ndarray],
+                          pairwise: Sequence[np.ndarray]) -> List[np.ndarray]:
+    """Enumerate the full joint. Only for tiny graphs (prod of states <~ 1e7)."""
+    log_joint = _log_joint(n_vertices, edges, unary, pairwise)
     z = _logsumexp(log_joint.ravel(), axis=0)
     marginals = []
     for v in range(n_vertices):
@@ -45,6 +54,16 @@ def brute_force_marginals(n_vertices: int, edges: np.ndarray,
         lm = _logsumexp(log_joint, axis=axes) - z
         marginals.append(np.exp(lm))
     return marginals
+
+
+def brute_force_map(n_vertices: int, edges: np.ndarray,
+                    unary: Sequence[np.ndarray],
+                    pairwise: Sequence[np.ndarray]) -> np.ndarray:
+    """(n_vertices,) the most probable assignment, by enumerating the full
+    joint (the first in row-major order among ties). Only for tiny graphs
+    (prod of states <~ 1e7)."""
+    log_joint = _log_joint(n_vertices, edges, unary, pairwise)
+    return np.array(np.unravel_index(np.argmax(log_joint), log_joint.shape))
 
 
 class _Factor:

@@ -166,14 +166,17 @@ def propagate_max(log_psi_e: jax.Array, pre: jax.Array) -> jax.Array:
 
 def max_product_update(pgm: PGM, logm: jax.Array):
     """ref_update for MAP inference (max-product). Messages renormalized to
-    max 0 over valid states (the standard max-product normalization)."""
+    max 0 over valid states (the standard max-product normalization). The
+    Pallas kernel's max body (``pallas_update(..., semiring="max")``)
+    matches it bitwise."""
     pre = edge_prelude(pgm, logm)
-    cand = propagate_max(pgm.log_psi_e, pre)
-    dst_mask = pgm.state_mask[pgm.edge_dst]
-    cand = jnp.where(dst_mask, cand, NEG_INF)
-    z = jnp.max(jnp.where(dst_mask, cand, NEG_INF), axis=1)
-    cand = jnp.where(dst_mask, cand - z[:, None], NEG_INF)
-    return cand, residuals(pgm, logm, cand)
+    with jax.named_scope("bp.update"):
+        cand = propagate_max(pgm.log_psi_e, pre)
+        dst_mask = pgm.state_mask[pgm.edge_dst]
+        cand = jnp.where(dst_mask, cand, NEG_INF)
+        z = jnp.max(jnp.where(dst_mask, cand, NEG_INF), axis=1)
+        cand = jnp.where(dst_mask, cand - z[:, None], NEG_INF)
+        return cand, residuals(pgm, logm, cand)
 
 
 def map_assignment(pgm: PGM, logm: jax.Array) -> jax.Array:

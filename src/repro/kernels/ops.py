@@ -5,6 +5,10 @@
 handles the transpose to kernel layout and edge padding to the block size.
 On the CPU it runs the Pallas interpreter; on a TPU it compiles the kernel.
 
+``semiring="max"`` runs the kernel's max-product body instead (MAP, a
+``max_product_update`` equivalent); it is registered as ``"pallas_max"``,
+so ``BPConfig(backend="pallas_max")`` reaches it by name.
+
 ``pallas_update_t`` is the layout-native variant used by the perf-tuned BP
 loop, which keeps messages transposed (S, E) across rounds so the two
 transposes per round disappear (see EXPERIMENTS.md SSPerf, BP iterations).
@@ -82,9 +86,11 @@ def kernel_operands_t(pgm: PGM):
         return logpsi_t, dmask_t
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def pallas_update(pgm: PGM, logm: jax.Array, *, interpret: bool | None = None):
-    """(cand (E,S), resid (E,)) -- kernel-backed ref_update equivalent."""
+@functools.partial(jax.jit, static_argnames=("interpret", "semiring"))
+def pallas_update(pgm: PGM, logm: jax.Array, *, interpret: bool | None = None,
+                  semiring: str = "sum"):
+    """(cand (E,S), resid (E,)) -- kernel-backed ``ref_update`` (or, with
+    ``semiring="max"``, ``max_product_update``) equivalent."""
     interpret = _resolve_interpret("pallas", interpret)
     pre = M.edge_prelude(pgm, logm)                          # (E, S)
     logpsi_t, dmask_t = kernel_operands_t(pgm)
@@ -92,24 +98,27 @@ def pallas_update(pgm: PGM, logm: jax.Array, *, interpret: bool | None = None):
         pre_t, logm_t = pre.T, logm.T
     with jax.named_scope("bp.update"):
         new_t, resid = fused_update_t(
-            logpsi_t, pre_t, logm_t, dmask_t, interpret=interpret)
+            logpsi_t, pre_t, logm_t, dmask_t, interpret=interpret,
+            semiring=semiring)
     with jax.named_scope("bp.layout"):
         return new_t.T, resid
 
 
-def make_pallas_update(interpret: bool | None = None):
+def make_pallas_update(interpret: bool | None = None, *,
+                       semiring: str = "sum"):
     """Static-arg-free closure suitable for ``run_bp(update_fn=...)``."""
     interpret = _resolve_interpret("pallas", interpret)
 
     def update_fn(pgm: PGM, logm: jax.Array):
-        return pallas_update(pgm, logm, interpret=interpret)
+        return pallas_update(pgm, logm, interpret=interpret,
+                             semiring=semiring)
 
     return update_fn
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "semiring"))
 def pallas_update_batch(bpgm: PGM, logm: jax.Array, *,
-                        interpret: bool | None = None):
+                        interpret: bool | None = None, semiring: str = "sum"):
     """(cand (B,E,S), resid (B,E)) over a stacked element-PGM whose leaves
     carry a leading batch axis (``BatchedPGM.pgm``). The batch axis is folded
     into the kernel's edge axis: one launch, grid = ceil(B*E / BLK_E).
@@ -127,18 +136,20 @@ def pallas_update_batch(bpgm: PGM, logm: jax.Array, *,
         pre_t, logm_t = pre.reshape(b * e, s).T, logm.reshape(b * e, s).T
     with jax.named_scope("bp.update"):
         new_t, resid = fused_update_t(logpsi_t, pre_t, logm_t, dmask_t,
-                                      interpret=interpret)
+                                      interpret=interpret, semiring=semiring)
     with jax.named_scope("bp.layout"):
         return new_t.T.reshape(b, e, s), resid.reshape(b, e)
 
 
-def make_pallas_update_batch(interpret: bool | None = None):
+def make_pallas_update_batch(interpret: bool | None = None, *,
+                             semiring: str = "sum"):
     """``batch_update_fn`` closure for the engine's batched path: whole-
     bucket fused message update in one kernel launch."""
     interpret = _resolve_interpret("pallas", interpret)
 
     def batch_update_fn(bpgm: PGM, logm: jax.Array):
-        return pallas_update_batch(bpgm, logm, interpret=interpret)
+        return pallas_update_batch(bpgm, logm, interpret=interpret,
+                                   semiring=semiring)
 
     return batch_update_fn
 
@@ -208,7 +219,8 @@ def make_triton_update_batch(interpret: bool | None = None, *,
 
 # ------------------------------------------------- backend registry ------
 # Message-update backends addressable by BPConfig.backend string. "ref" is
-# the pure-jnp oracle; "pallas" the fused kernel (interpreted on the CPU).
+# the pure-jnp oracle; "pallas" the fused kernel (interpreted on the CPU);
+# "maxprod" and "pallas_max" the same pair for max-product.
 # Batched entries return a natively batched (B, E, S) update; the engine's
 # default batched path instead folds the bucket and reuses the single-graph
 # backend, so only register a batched entry when it beats the fold.
@@ -230,6 +242,9 @@ UPDATE_BACKENDS = Registry("update backend", {
     # with BPConfig(backend="maxprod") and map_assignment on the result.
     "maxprod": lambda: M.max_product_update,
     "pallas": make_pallas_update,
+    # The same TPU kernel's max-product body: MAP with the update fused on
+    # the chip ("maxprod" stays as its pure-jnp oracle).
+    "pallas_max": functools.partial(make_pallas_update, semiring="max"),
     # GPU-class fused kernel (Pallas Triton lowering, edge-major blocks,
     # states in registers; interpreted on the CPU so CPU CI exercises the
     # same program). semiring="max" kwarg serves MAP.
@@ -245,6 +260,8 @@ UPDATE_BACKENDS = Registry("update backend", {
 
 BATCH_UPDATE_BACKENDS = Registry("batched update backend", {
     "pallas": make_pallas_update_batch,
+    "pallas_max": functools.partial(make_pallas_update_batch,
+                                    semiring="max"),
     "triton": make_triton_update_batch,
 })
 

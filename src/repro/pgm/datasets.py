@@ -27,6 +27,10 @@ Stereo-vision MRF (the paper's vision motivation): a rectangular grid over
 a synthetic disparity scene with truncated-linear data and smoothness
 terms -- the classic stereo energy, and at image scale the natural stress
 test for the banded dist path (``stereo_mrf`` / ``stereo_graph``).
+``stereo_pair_mrf`` is the matching energy of Felzenszwalb & Huttenlocher
+(Efficient Belief Propagation for Early Vision, IJCV 70(1), 2006, section
+4): data costs from the intensity differences of a synthetic rectified
+image pair, truncated-linear smoothness, solved for MAP by max-product.
 
 The ``WORKLOADS`` registry names every zoo member
 (``register_workload`` / ``list_workloads`` / ``get_workload``) and
@@ -47,11 +51,12 @@ from repro.core.graph import PGM, build_pgm, build_pgm_uniform
 from repro.core.registry import Registry
 
 __all__ = [
-    "LDPCInstance", "StereoInstance", "WORKLOADS", "chain_graph",
+    "LDPCInstance", "StereoInstance", "StereoPairInstance", "WORKLOADS",
+    "chain_graph",
     "get_workload", "ising_grid", "ising_grid_fast", "ldpc_code",
     "ldpc_graph", "list_workloads", "loop_graph", "protein_like_graph",
     "register_workload", "small_ising", "stereo_graph", "stereo_mrf",
-    "zoo_stream",
+    "stereo_pair_mrf", "zoo_stream",
 ]
 
 
@@ -406,6 +411,130 @@ def stereo_graph(seed: int = 0, *, height: int = 12, width: int = 16,
     return stereo_mrf(height, width, n_disp, seed=seed, **kwargs).pgm
 
 
+#: Gaussian low-pass of ``stereo_pair_mrf``'s texture, in pixels: grey
+#: levels of neighbouring pixels correlate as in natural images, and the
+#: texture needs no pre-smoothing (F&H smooth theirs by 0.7).
+_TEXTURE_SIGMA = 1.5
+
+
+def _texture(rng: np.random.Generator, height: int, width: int,
+             sigma: float) -> np.ndarray:
+    """(H, W) band-limited 8-bit texture: white noise low-passed by a
+    separable Gaussian of ``sigma`` pixels, stretched to a standard
+    deviation of 50 grey levels about 128, rounded and clipped to 0..255."""
+    r = max(1, int(np.ceil(3 * sigma)))
+    k = np.exp(-0.5 * (np.arange(-r, r + 1) / sigma) ** 2)
+    k /= k.sum()
+    img = rng.standard_normal((height, width))
+    img = np.pad(img, r, mode="symmetric")
+    img = sum(w * img[:, j:j + width] for j, w in enumerate(k))
+    img = sum(w * img[j:j + height, :] for j, w in enumerate(k))
+    img = 128.0 + 50.0 * (img - img.mean()) / max(img.std(), 1e-12)
+    return np.clip(np.round(img), 0, 255)
+
+
+@dataclasses.dataclass(frozen=True)
+class StereoPairInstance:
+    """One synthetic rectified stereo pair and its matching MRF.
+
+    ``truth`` is the disparity map (``stereo_mrf``'s scene: a slanted plane
+    with a raised rectangle); ``left`` and ``right`` are the 8-bit images,
+    the right one the left shifted by ``truth``. Vertices are pixels in
+    row-major order with ``n_disp`` states; every grid edge shares the one
+    ``smooth`` table.
+    Decode with max-product and score by :meth:`energy` and
+    :meth:`accuracy`."""
+
+    pgm: PGM
+    height: int
+    width: int
+    n_disp: int
+    truth: np.ndarray                   # (H, W) int ground-truth disparity
+    left: np.ndarray                    # (H, W) float 8-bit grey levels
+    right: np.ndarray                   # (H, W) float 8-bit grey levels
+    edges: np.ndarray                   # (E, 2) grid edges
+    unary: np.ndarray                   # (H*W, n_disp) exp(-data cost)
+    smooth: np.ndarray                  # (n_disp, n_disp) exp(-V(d_p, d_q))
+
+    def raw(self):
+        """``(n_vertices, edges, unary, pairwise)`` for the exact oracles."""
+        n = self.height * self.width
+        return (n, [tuple(e) for e in self.edges],
+                [self.unary[i] for i in range(n)],
+                [self.smooth] * len(self.edges))
+
+    def energy(self, labels: np.ndarray) -> float:
+        """Data plus smoothness cost of a disparity labeling (negative
+        log-potential; lower is better): the MAP objective."""
+        lbl = np.asarray(labels).reshape(-1)[: self.height * self.width]
+        e = -float(np.sum(np.log(self.unary[np.arange(lbl.size), lbl])))
+        return e - float(np.sum(np.log(
+            self.smooth[lbl[self.edges[:, 0]], lbl[self.edges[:, 1]]])))
+
+    def accuracy(self, labels: np.ndarray, slack: int = 1) -> float:
+        """Fraction of pixels whose disparity is within ``slack`` of the
+        truth."""
+        lbl = np.asarray(labels).reshape(-1)[: self.height * self.width]
+        return float(np.mean(
+            np.abs(lbl - self.truth.reshape(-1)) <= slack))
+
+    def winner_take_all(self) -> np.ndarray:
+        """(H*W,) each pixel's cheapest disparity by the data term alone."""
+        return np.argmax(self.unary, axis=1)
+
+
+def stereo_pair_mrf(height: int = 12, width: int = 16, n_disp: int = 8, *,
+                    seed: int = 0, lam_data: float = 0.07,
+                    trunc_data: float = 15.0, trunc_smooth: float = 1.7,
+                    noise: float = 2.0) -> StereoPairInstance:
+    """Stereo matching MRF of a synthetic rectified pair (Felzenszwalb &
+    Huttenlocher, IJCV 2006, section 4, whose Tsukuba experiment is
+    384x288 with 16 labels and these default constants).
+
+    The left image is a seeded band-limited texture (``_TEXTURE_SIGMA``).
+    The right image is the left one shifted by the truth disparity
+    (``I_R(x - d, y) = I_L(x, y)``; nearer pixels win where two land on
+    one); right pixels that no left pixel reaches get a second texture.
+    Both get Gaussian noise of ``noise`` grey levels. Unaries are
+    ``exp(-lam_data * min(|I_L(x, y) - I_R(x - d, y)|, trunc_data))``,
+    with the cost ``lam_data * trunc_data`` where ``x - d < 0``; every edge
+    has ``exp(-min(|d_p - d_q|, trunc_smooth))``. Only one ``n_disp`` x
+    ``n_disp`` table is held on the host (a broadcast view per edge)."""
+    rng = np.random.default_rng(seed)
+    _, cc = np.meshgrid(np.arange(height), np.arange(width), indexing="ij")
+    truth = np.clip(np.round((cc / max(width - 1, 1)) * (n_disp // 2)),
+                    0, n_disp - 1).astype(int)
+    fh, fw = max(1, height // 3), max(1, width // 3)
+    r0, c0 = height // 4, width // 4
+    truth[r0:r0 + fh, c0:c0 + fw] = max(n_disp - 2, 0)
+
+    left = _texture(rng, height, width, _TEXTURE_SIGMA)
+    right = _texture(rng, height, width, _TEXTURE_SIGMA)
+    rows, cols = np.nonzero(np.ones((height, width), bool))
+    for d in range(n_disp):             # ascending: nearer pixels win
+        at = (truth[rows, cols] == d) & (cols >= d)
+        right[rows[at], cols[at] - d] = left[rows[at], cols[at]]
+    left = np.clip(left + rng.normal(0.0, noise, left.shape), 0, 255)
+    right = np.clip(right + rng.normal(0.0, noise, right.shape), 0, 255)
+
+    cost = np.full((height, width, n_disp), lam_data * trunc_data)
+    for d in range(min(n_disp, width)):   # x - d < 0 keeps the truncation
+        diff = np.abs(left[:, d:] - right[:, :width - d])
+        cost[:, d:, d] = lam_data * np.minimum(diff, trunc_data)
+    unary = np.exp(-cost.reshape(height * width, n_disp))
+    lbl = np.arange(n_disp)
+    smooth = np.exp(-np.minimum(np.abs(lbl[:, None] - lbl[None, :]),
+                                trunc_smooth))
+    smooth.setflags(write=False)
+    edges = _grid_edges_rect(height, width)
+    pairwise = np.broadcast_to(smooth, (len(edges), n_disp, n_disp))
+    pgm = build_pgm_uniform(height * width, edges, unary, pairwise)
+    return StereoPairInstance(pgm=pgm, height=height, width=width,
+                              n_disp=n_disp, truth=truth, left=left,
+                              right=right, edges=edges, unary=unary,
+                              smooth=smooth)
+
+
 # ----------------------------------------------------- workload registry --
 
 #: name -> ``fn(seed=0, **size_kwargs) -> PGM`` zoo generator. A
@@ -465,6 +594,14 @@ def _stereo_workload(seed: int = 0, *, height: int = 12, width: int = 16,
     return stereo_graph(seed, height=height, width=width, n_disp=n_disp)
 
 
+@register_workload("stereo_pair")
+def _stereo_pair_workload(seed: int = 0, *, height: int = 12,
+                          width: int = 16, n_disp: int = 8) -> PGM:
+    """Stereo-matching zoo member (F&H 2006 energy of a synthetic image
+    pair): one fresh pair per seed; solve for MAP with max-product."""
+    return stereo_pair_mrf(height, width, n_disp, seed=seed).pgm
+
+
 #: ``zoo_stream``'s interleave table: (kind, size kwargs) per slot. Two
 #: size variants per kind, so a stream mixes shapes *within* each kind too
 #: -- the bucketing/admission stressor.
@@ -478,6 +615,8 @@ _ZOO_VARIANTS: Tuple[Tuple[str, dict], ...] = (
     ("chain", dict(n=300)),
     ("ldpc", dict(n=48, dv=3, dc=6)),
     ("stereo", dict(height=8, width=10, n_disp=5)),
+    ("stereo_pair", dict(height=5, width=7, n_disp=4)),
+    ("stereo_pair", dict(height=7, width=9, n_disp=6)),
 )
 
 

@@ -15,9 +15,11 @@ Oracles and tolerances are per-backend:
   the fused kernel, so beliefs match to ~1e-4 and round counts to a small
   drift (residual-threshold crossings can shift by ulps).
 - ``sharded`` adds a cross-device edge split on top -- 5e-3.
-- ``maxprod`` is compared against ``triton(semiring="max")`` -- a true
-  differential pair (two independent implementations of the max semiring);
-  max reductions are order-exact so agreement is near-bitwise.
+- ``maxprod`` and ``pallas_max`` are compared against
+  ``triton(semiring="max")`` -- true differential pairs (independent
+  implementations of the max semiring); max reductions are order-exact so
+  agreement is near-bitwise. A backend's semiring is read from
+  ``SEMIRING`` alone.
 
 Also here: the chunked-resume bitwise contract per backend (N rounds via
 ``step`` == N rounds in one ``run``), serving-stack parity for the triton
@@ -72,10 +74,19 @@ CORPUS = {
 TOLERANCE = {
     "ref": (0.0, True),
     "maxprod": (1e-6, True),
+    "pallas_max": (1e-6, True),
     "pallas": (1e-4, False),
     "triton": (1e-4, False),
     "sharded": (5e-3, False),
 }
+
+#: The max-product backends; every other backend is sum-product.
+SEMIRING = {"maxprod": "max", "pallas_max": "max"}
+
+
+def semiring_of(backend: str) -> str:
+    return SEMIRING.get(backend, "sum")
+
 
 BACKENDS = list_backends()
 SCHEDULERS = list_schedulers()
@@ -116,7 +127,7 @@ class TestBackendSchedulerMatrix:
     @pytest.mark.parametrize("backend", BACKENDS)
     @pytest.mark.parametrize("scheduler", SCHEDULERS)
     def test_backend_matches_reference(self, backend, scheduler):
-        semiring = "max" if backend == "maxprod" else "sum"
+        semiring = semiring_of(backend)
         # Max-product oscillates forever on the loopy ising grid (ties in
         # the max make the fixed point unstable) -- a semiring property,
         # not a backend bug -- so the max rotation skips that graph.
@@ -145,8 +156,8 @@ class TestBackendSchedulerMatrix:
     def test_matrix_is_complete(self):
         """The enumeration really covers the live registries (a regression
         here means a backend/scheduler was registered but not conformed)."""
-        assert set(BACKENDS) >= {"ref", "maxprod", "pallas", "triton",
-                                 "sharded"}
+        assert set(BACKENDS) >= {"ref", "maxprod", "pallas", "pallas_max",
+                                 "triton", "sharded"}
         assert set(SCHEDULERS) >= {"lbp", "rbp", "rlx", "rlxtree", "rnbp",
                                    "rs"}
         assert set(TOLERANCE) >= set(BACKENDS)
@@ -167,7 +178,7 @@ class TestChunkedResumePerBackend:
 
     @pytest.mark.parametrize("backend", BACKENDS)
     def test_chunked_equals_monolithic(self, backend):
-        gname = "ldpc" if backend == "maxprod" else "ising"
+        gname = "ldpc" if semiring_of(backend) == "max" else "ising"
         pgm = corpus_pgm(gname)
         eng = BPEngine(BPConfig(scheduler="rs", eps=EPS,
                                 max_rounds=MAX_ROUNDS, backend=backend))

@@ -4,7 +4,8 @@ compiler and checks that the kernel is in the compiled program
 (``tpu_custom_call``) rather than an interpreter loop. The shapes are the
 main path's real widths: Ising 200x200 (S=2), the Tsukuba-size stereo MRF
 (S=16), LDPC check vertices (S=32), protein-like graphs (S=47), and a
-4-graph bucket through the batch fold.
+4-graph bucket through the batch fold; the max-product body at the
+Tsukuba-size stereo pair's and the Ising grid's widths.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and the test workers all import
@@ -63,6 +64,24 @@ def test_fused_update_compiles_for_v5e(one_chip, name):
     args = (_spec((s, s, e), f32, one_chip), _spec((s, e), f32, one_chip),
             _spec((s, e), f32, one_chip), _spec((s, e), jnp.bool_, one_chip))
     compiled = fused_update_t.lower(*args, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+#: (S, E) of the max-product body's calls at real sizes.
+MAX_SHAPES = {
+    "stereo_tsukuba": (16, 441_088),
+    "ising200": (2, 159_232),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MAX_SHAPES))
+def test_max_body_compiles_for_v5e(one_chip, name):
+    s, e = MAX_SHAPES[name]
+    f32 = jnp.float32
+    args = (_spec((s, s, e), f32, one_chip), _spec((s, e), f32, one_chip),
+            _spec((s, e), f32, one_chip), _spec((s, e), jnp.bool_, one_chip))
+    compiled = fused_update_t.lower(*args, interpret=False,
+                                    semiring="max").compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 

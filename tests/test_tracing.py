@@ -19,8 +19,9 @@ from repro.core.graph import BUILD_EVENT, build_pgm, build_pgm_uniform
 from repro.pgm import ising_grid_fast
 
 ROUND_SCOPES = ("bp.prelude", "bp.update", "bp.select", "bp.commit")
-#: Only the Pallas path reshapes operands into the kernel's (S, E) layout.
-LAYOUT_SCOPE = {"ref": (), "pallas": ("bp.layout",)}
+#: Only the Pallas paths reshape operands into the kernel's (S, E) layout.
+LAYOUT_SCOPE = {"ref": (), "pallas": ("bp.layout",), "maxprod": (),
+                "pallas_max": ("bp.layout",)}
 RUN_SPANS = ("bp.init", "bp.step", "bp.finished", "bp.result")
 
 
@@ -45,7 +46,7 @@ def _chunk_text(eng, state):
     return lowered.as_text(debug_info=True)
 
 
-@pytest.mark.parametrize("backend", ["ref", "pallas"])
+@pytest.mark.parametrize("backend", sorted(LAYOUT_SCOPE))
 def test_round_phases_are_scoped_in_both_chunks(backend):
     eng = _engine(backend)
     pgms = [ising_grid_fast(4, 2.0, seed=s) for s in (0, 1)]
@@ -57,7 +58,7 @@ def test_round_phases_are_scoped_in_both_chunks(backend):
             # A path component; a nested jit's locations start a new path.
             assert re.search(rf'["/]{re.escape(scope)}/', text), (
                 backend, state.batched, scope)
-    if backend == "ref":
+    if not LAYOUT_SCOPE[backend]:
         assert "bp.layout" not in _chunk_text(eng, single)
 
 

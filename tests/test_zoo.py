@@ -54,6 +54,7 @@ _SMALL = {
     "protein": dict(n_vertices=10),
     "ldpc": dict(n=12, dv=2, dc=4),
     "stereo": dict(height=4, width=5, n_disp=3),
+    "stereo_pair": dict(height=4, width=5, n_disp=3),
 }
 
 
@@ -145,7 +146,7 @@ class TestZooStructure:
                                   np.asarray(c.log_psi_v))
 
     def test_zoo_stream_mixes_kinds_and_sizes(self):
-        items = list(zoo_stream(9, seed=0))
+        items = list(zoo_stream(11, seed=0))
         kinds = {k for k, _ in items}
         assert kinds == set(list_workloads())
         shapes = {(int(p.n_edges), int(p.n_vertices)) for _, p in items}

@@ -227,7 +227,9 @@ def _chunk_single(pgm: PGM, carry, limit, eps, *, scheduler: Scheduler,
                   damping: float, update_fn: Callable, track_history: bool):
     """Run the frontier loop (paper Algorithm 1) until convergence or
     ``rounds >= limit``. Body identical to the historic ``run_bp`` loop, so
-    chunked execution reproduces monolithic trajectories bit-for-bit."""
+    chunked execution reproduces monolithic trajectories bit-for-bit. The
+    prelude's source-state mask is gathered here, once per chunk."""
+    pgm = M.with_src_mask(pgm)
 
     def cond(c):
         _, _, _, rounds, done, _, _, _, _ = c
@@ -286,15 +288,17 @@ def _chunk_batch(batch: BatchedPGM, carry, limit, eps, *,
     per-graph ``limit`` (B,). Each graph's body effects are gated on its own
     ``active`` flag, so graphs at different cumulative rounds (evacuation
     backfill) each reproduce their solo trajectory exactly: a frozen graph
-    commits nothing, consumes no RNG, and advances no counters."""
-    bpgm = batch.pgm
+    commits nothing, consumes no RNG, and advances no counters. The
+    prelude's source-state masks are gathered here, once per chunk."""
+    bpgm = jax.vmap(M.with_src_mask)(batch.pgm)
     b, e = batch.size, batch.n_edges
     s = batch.n_states_max
     if batch_update_fn is None:
         # Mesh-aware fold: a sharded backend (repro.dist) advertises its
         # mesh, and the (B*E) union grid is laid out across it.
-        union = batch.folded(mesh=getattr(update_fn, "mesh", None),
-                             axis=getattr(update_fn, "axis", "bp"))
+        union = M.with_src_mask(batch.folded(
+            mesh=getattr(update_fn, "mesh", None),
+            axis=getattr(update_fn, "axis", "bp")))
 
         def batch_update_fn(_, logm):
             cand, r = update_fn(union, logm.reshape(b * e, s))

@@ -96,6 +96,11 @@ class PGM:
           only where it is compact (``IN_EDGE_SLACK``); every re-padded,
           stacked, folded or sharded PGM carries None and sums by
           ``segment_sum``.
+      src_mask                     : (E, S) bool or None:
+          ``state_mask[edge_src]``, the source states each edge's message
+          is computed over. The engine's round loops fill it once per
+          chunk (``messages.with_src_mask``); where it is None the prelude
+          gathers it each call.
     """
 
     edge_src: jax.Array
@@ -118,6 +123,7 @@ class PGM:
     edge_count: jax.Array | None = None
     vertex_count: jax.Array | None = None
     in_edges: jax.Array | None = None
+    src_mask: jax.Array | None = None
 
     @property
     def n_edges(self) -> int:

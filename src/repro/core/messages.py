@@ -13,6 +13,13 @@ In log space with a per-vertex "incoming sum" cache:
     pre[e]    = log_psi_v[src] + vsum[src] - logm[rev(e)]            (exclude j->i)
     cand[e,j] = LSE_{x_i}( log_psi_e[e, x_i, x_j] + pre[e, x_i] )    (hot spot)
 
+``pre`` is NEG_INF at states the source vertex lacks. That (E, S) source
+mask depends on the graph only, so the engine's round loops gather it once
+per chunk (``with_src_mask``) and the prelude gathers it itself only for a
+PGM that does not carry it. Each trace of the prelude records how many
+(E, S) source-side gathers it emitted as the ``jax.monitoring`` scalar
+``SRC_GATHERS``.
+
 ``cand`` is then normalized (LSE over valid dst states == 0). The LSE hot spot
 is what the Pallas kernel in ``repro.kernels.message_update`` implements; this
 module is the oracle (``ref.py`` re-exports from here) and the CPU path.
@@ -22,11 +29,19 @@ Residual (paper Eq. 4): r(m) = || f_BP(m) - m ||_inf over valid states.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
 from repro.core.graph import NEG_INF, PGM
 from repro.kernels.explog import exp, log
+
+#: ``jax.monitoring`` scalar, recorded each time ``edge_prelude`` is traced:
+#: the (E, S) gathers it emitted through ``edge_src``. 2 (``log_psi_v`` and
+#: ``vsum``) where the PGM carries ``src_mask``, 3 where the prelude gathered
+#: the mask too.
+SRC_GATHERS = "/repro/prelude/src_gathers"
 
 
 def masked_logsumexp(x: jax.Array, mask: jax.Array, axis: int) -> jax.Array:
@@ -72,6 +87,15 @@ def vertex_logprod(pgm: PGM, logm: jax.Array,
     return total if onto is None else onto + total
 
 
+def with_src_mask(pgm: PGM) -> PGM:
+    """``pgm`` carrying ``src_mask``, its (E, S) ``state_mask[edge_src]``:
+    gathered once here, so that every ``edge_prelude`` on the result reads
+    the mask instead of gathering it."""
+    with jax.named_scope("bp.prelude"):
+        return dataclasses.replace(
+            pgm, src_mask=pgm.state_mask[pgm.edge_src])
+
+
 def edge_prelude(pgm: PGM, logm: jax.Array,
                  vsum: jax.Array | None = None) -> jax.Array:
     """(E, S) per-edge source-side belief excluding the reverse message.
@@ -82,7 +106,11 @@ def edge_prelude(pgm: PGM, logm: jax.Array,
         pre = (pgm.log_psi_v[pgm.edge_src]
                + vsum[pgm.edge_src]
                - logm[pgm.edge_rev])
-        src_mask = pgm.state_mask[pgm.edge_src]
+        src_mask = pgm.src_mask
+        jax.monitoring.record_scalar(SRC_GATHERS,
+                                     3 if src_mask is None else 2)
+        if src_mask is None:
+            src_mask = pgm.state_mask[pgm.edge_src]
         return jnp.where(src_mask, pre, NEG_INF)
 
 

@@ -5,7 +5,9 @@ compiler and checks that the kernel is in the compiled program
 main path's real widths: Ising 200x200 (S=2), the Tsukuba-size stereo MRF
 (S=16), LDPC check vertices (S=32), protein-like graphs (S=47), and a
 4-graph bucket through the batch fold; the max-product body at the
-Tsukuba-size stereo pair's and the Ising grid's widths.
+Tsukuba-size stereo pair's and the Ising grid's widths; and the engine's
+whole round loop on the Ising 200x200 grid, whose compiled ``while`` body
+must not gather the source-state mask.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and the test workers all import
@@ -13,14 +15,18 @@ every test module. The persistent compilation cache is off while these
 compiles run: a TPU executable written to it could not be read back here.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.pgm import stereo_graph  # first: it initializes repro.core
+from repro.pgm import ising_grid_fast, stereo_graph  # first: repro.core
+from repro.core import BPConfig, BPEngine
+from repro.core import engine as E
 from repro.kernels.message_update import fused_update_t
-from repro.kernels.ops import pallas_update_batch
+from repro.kernels.ops import make_pallas_update, pallas_update_batch
 
 #: (S, E): states and edges of the kernel call at real sizes.
 KERNEL_SHAPES = {
@@ -94,3 +100,45 @@ def test_bucket_fold_compiles_for_v5e(one_chip):
     compiled = pallas_update_batch.lower(bpgm, logm,
                                          interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _computations(text):
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    out, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name, out[head.group(1)] = head.group(1), []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            out[name].append(line)
+    return out
+
+
+def test_ising_round_loop_has_no_source_mask_gather(one_chip):
+    """The v5e-compiled Ising 200x200 round loop: its ``while`` body holds
+    the three f32 (E, 2) gathers of ``pre`` (``log_psi_v`` and ``vsum``
+    through ``edge_src``, ``logm[edge_rev]``) and no pred (E, 2) gather: the
+    source-state mask is gathered before the loop."""
+    pgm = ising_grid_fast(200, 2.0, seed=0)
+    eng = BPEngine(BPConfig(scheduler="rnbp", eps=1e-3, max_rounds=2000,
+                            backend=make_pallas_update(interpret=False)))
+    state = eng.init(pgm, jax.random.key(0))
+    spec = lambda x: _spec(x.shape, x.dtype, one_chip)  # noqa: E731
+    cfg = eng.config
+    text = E._chunk_single.lower(
+        jax.tree.map(spec, pgm), jax.tree.map(spec, E._carry_of(state)),
+        spec(state.rounds), cfg.eps, scheduler=eng.scheduler,
+        damping=cfg.damping, update_fn=eng.update_fn,
+        track_history=cfg.history).compile().as_text()
+    assert "tpu_custom_call" in text
+    comps = _computations(text)
+    body = re.search(r" while\(.*body=%([^,\s]+)", text).group(1)
+    fused = [c for line in comps[body]
+             for c in re.findall(r"calls=%([^,\s]+)", line)]
+    rows = f"[{pgm.n_edges},{pgm.n_states_max}]"
+    gathers = [re.match(r"\s*%\S+ = (\w+)\[", line).group(1)
+               for c in [body] + fused for line in comps[c]
+               if f"{rows}{{" in line and " gather(" in line]
+    assert sorted(gathers) == ["f32", "f32", "f32"], gathers

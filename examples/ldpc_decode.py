@@ -12,8 +12,7 @@ For each SNR point the all-zero codeword is transmitted ``--words`` times
 with fresh noise; the coded bit-error rate (max-product MAP + argmax
 beliefs) is compared against the uncoded hard-decision BER on the same
 received samples. The coded curve must drop below uncoded -- that gap is
-the decoder doing real work, and ``benchmarks/bench_zoo.py`` pins it as an
-acceptance number.
+the decoder doing real work, and ``tests/test_zoo.py`` pins it.
 
 Run:  PYTHONPATH=src python examples/ldpc_decode.py [--words 8] \
           [--snr 1.0,2.0,3.0] [--n 48] [--scheduler rbp]

@@ -7,9 +7,9 @@ root of that checkout (gitignored): a fixed path, so a later run in the same
 checkout finds what an earlier one compiled. An installed ``repro`` has no
 checkout, and there the cache stays off unless the variable names one.
 
-Only entry points call this (``chip_smoke.py``, ``examples/*.py``,
-``benchmarks/run.py``); importing ``repro`` sets no cache, so tests run
-without one unless their environment names a directory.
+Only entry points call this (``chip_smoke.py``, ``examples/*.py``);
+importing ``repro`` sets no cache, so tests run without one unless their
+environment names a directory.
 """
 
 from __future__ import annotations

@@ -547,7 +547,6 @@ def run_bp_batch(batch: BatchedPGM,
                  max_rounds: int = 2000,
                  damping: float = 0.0,
                  update_fn: Callable | None = None,
-                 batch_update_fn: Callable | None = None,
                  track_history: bool = False):
     """Deprecated wrapper: ``BPEngine(BPConfig(...)).run(batch, rng)``.
 
@@ -561,7 +560,7 @@ def run_bp_batch(batch: BatchedPGM,
     cfg = BPConfig(scheduler=scheduler, eps=eps, max_rounds=max_rounds,
                    damping=damping,
                    backend=update_fn if update_fn is not None else "ref",
-                   batch_backend=batch_update_fn, history=track_history)
+                   history=track_history)
     return BPEngine(cfg).run(batch, rng)
 
 
@@ -582,7 +581,6 @@ def run_bp_many(pgms: Sequence[PGM],
                    max_rounds=bp_kwargs.pop("max_rounds", 2000),
                    damping=bp_kwargs.pop("damping", 0.0),
                    backend=bp_kwargs.pop("update_fn", None) or "ref",
-                   batch_backend=bp_kwargs.pop("batch_update_fn", None),
                    history=bp_kwargs.pop("track_history", False))
     if bp_kwargs:
         raise TypeError(f"unknown arguments: {sorted(bp_kwargs)}")

@@ -101,10 +101,8 @@ class BPConfig:
 
     ``backend`` picks the message-update implementation by name ("ref" |
     "pallas", resolved through ``repro.kernels.ops.UPDATE_BACKENDS``) or is a
-    ``(pgm, logm) -> (cand, resid)`` callable. ``batch_backend`` optionally
-    overrides the batched path with a natively batched update (callable or
-    "pallas"); the default folds the bucket into a disjoint union and reuses
-    the single-graph ``backend``.
+    ``(pgm, logm) -> (cand, resid)`` callable. A bucket runs the same
+    ``backend`` on its disjoint-union fold.
 
     ``chunk_rounds`` bounds rounds per ``step`` (None = run to
     ``max_rounds`` in one chunk); ``history`` sizes the per-round
@@ -125,7 +123,6 @@ class BPConfig:
     max_rounds: int = 2000
     damping: float = 0.0
     backend: Any = "ref"
-    batch_backend: Any = None
     chunk_rounds: int | None = None
     history: bool = True
     admission: Any = "fifo"
@@ -157,9 +154,8 @@ class BPConfig:
         if not isinstance(self.scheduler, str):
             name, kw = scheduler_spec(self.scheduler)
             d["scheduler"], d["scheduler_kwargs"] = name, _freeze_kwargs(kw)
-        for f in ("backend", "batch_backend"):
-            if d[f] is not None and not isinstance(d[f], str):
-                raise ValueError(f"{f} is a callable; not serializable")
+        if not isinstance(d["backend"], str):
+            raise ValueError("backend is a callable; not serializable")
         if not isinstance(d["admission"], str):
             raise ValueError("admission is a policy instance; use a registry "
                              "spec string for a serializable config")
@@ -280,29 +276,28 @@ def _bcast_where(mask: jax.Array, new: jax.Array, old: jax.Array) -> jax.Array:
 
 
 @partial(jax.jit, static_argnames=("scheduler", "damping", "update_fn",
-                                   "batch_update_fn", "track_history"))
+                                   "track_history"))
 def _chunk_batch(batch: BatchedPGM, carry, limit, eps, *,
                  scheduler: Scheduler, damping: float, update_fn: Callable,
-                 batch_update_fn: Callable | None, track_history: bool):
+                 track_history: bool):
     """Whole-bucket frontier loop until every graph converges or reaches its
     per-graph ``limit`` (B,). Each graph's body effects are gated on its own
     ``active`` flag, so graphs at different cumulative rounds (evacuation
     backfill) each reproduce their solo trajectory exactly: a frozen graph
     commits nothing, consumes no RNG, and advances no counters. The
-    prelude's source-state masks are gathered here, once per chunk."""
-    bpgm = jax.vmap(M.with_src_mask)(batch.pgm)
+    fold's source-state mask is gathered here, once per chunk."""
+    bpgm = batch.pgm
     b, e = batch.size, batch.n_edges
     s = batch.n_states_max
-    if batch_update_fn is None:
-        # Mesh-aware fold: a sharded backend (repro.dist) advertises its
-        # mesh, and the (B*E) union grid is laid out across it.
-        union = M.with_src_mask(batch.folded(
-            mesh=getattr(update_fn, "mesh", None),
-            axis=getattr(update_fn, "axis", "bp")))
+    # Mesh-aware fold: a sharded backend (repro.dist) advertises its mesh,
+    # and the (B*E) union grid is laid out across it.
+    union = M.with_src_mask(batch.folded(
+        mesh=getattr(update_fn, "mesh", None),
+        axis=getattr(update_fn, "axis", "bp")))
 
-        def batch_update_fn(_, logm):
-            cand, r = update_fn(union, logm.reshape(b * e, s))
-            return cand.reshape(b, e, s), r.reshape(b, e)
+    def batch_update(logm):
+        cand, r = update_fn(union, logm.reshape(b * e, s))
+        return cand.reshape(b, e, s), r.reshape(b, e)
 
     select = jax.vmap(
         lambda p, r, k, st, u: scheduler.select(p, r, eps, k, st, u))
@@ -319,7 +314,7 @@ def _chunk_batch(batch: BatchedPGM, carry, limit, eps, *,
             split = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
             keys = _where_keys(active, split[:, 0], keys)
             sel_keys = split[:, 1]
-        cand, r = batch_update_fn(bpgm, logm)
+        cand, r = batch_update(logm)
         with jax.named_scope("bp.select"):
             unconverged = jnp.sum((r >= eps) & bpgm.edge_mask,
                                   axis=1).astype(jnp.int32)     # (B,)
@@ -332,7 +327,7 @@ def _chunk_batch(batch: BatchedPGM, carry, limit, eps, *,
             frontier = frontier & active[:, None] & ~newly_done[:, None]
             logm = commit(logm, cand, frontier)
         for _ in range(scheduler.inner_sweeps - 1):
-            cand, _ = batch_update_fn(bpgm, logm)
+            cand, _ = batch_update(logm)
             with jax.named_scope("bp.commit"):
                 logm = commit(logm, cand, frontier)
         with jax.named_scope("bp.commit"):
@@ -461,18 +456,15 @@ class BPEngine:
         self.scheduler: Scheduler | None = (
             None if self.is_serial else config.make_scheduler())
         self.update_fn = self._resolve_backend(config.backend)
-        self.batch_update_fn = (
-            None if config.batch_backend is None
-            else self._resolve_backend(config.batch_backend, batched=True))
 
     @staticmethod
-    def _resolve_backend(backend, *, batched: bool = False) -> Callable:
+    def _resolve_backend(backend) -> Callable:
         if callable(backend):
             return backend
-        if backend == "ref" and not batched:
+        if backend == "ref":
             return M.ref_update
         from repro.kernels.ops import get_update_fn
-        return get_update_fn(backend, batched=batched)
+        return get_update_fn(backend)
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -506,8 +498,7 @@ class BPEngine:
                   update_fn=self.update_fn, track_history=cfg.history)
         if state.batched:
             carry = _chunk_batch(state.graph, _carry_of(state), limit,
-                                 cfg.eps, batch_update_fn=self.batch_update_fn,
-                                 **kw)
+                                 cfg.eps, **kw)
         else:
             carry = _chunk_single(state.graph, _carry_of(state), limit,
                                   cfg.eps, **kw)

@@ -29,7 +29,7 @@ shard when ``Q`` is a multiple of the mesh size). Each round:
 Net: the only cross-shard traffic left in a sharded round is the update's
 (V, S) psum plus O(Q)-scalar reductions -- no collective ever touches an
 edge-sized array (audited from the compiled HLO by
-``benchmarks/bench_tradeoff.py``).
+``tests/test_schedulers.py``).
 """
 
 from __future__ import annotations

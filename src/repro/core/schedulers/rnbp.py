@@ -10,8 +10,8 @@ Frontier = two filters over all directed edges:
 Dynamic p (SS IV-A): track EdgeRatio = NewEdgeCount / OldEdgeCount of
 unconverged edges between consecutive rounds. EdgeRatio > 0.9 means the run
 is stalling -> use LowP (sequentialism, convergence mode); otherwise HighP
-(parallelism, speed mode). The paper locks HighP = 1.0 for the synthetic
-benchmarks and sweeps LowP in {0.7, 0.4, 0.1}; protein runs use (0.9, 0.4).
+(parallelism, speed mode). The paper locks HighP = 1.0 for its synthetic
+graphs and sweeps LowP in {0.7, 0.4, 0.1}; protein runs use (0.9, 0.4).
 
 Carried state: previous round's unconverged-edge count.
 """

@@ -542,7 +542,8 @@ class ResidualAdmission(AdmissionPolicy):
     of the slot's live occupants. Fast-converging requests ride
     fast buckets that release early; long-running ones co-batch and do
     useful work together -- the gated chunk body then wastes no sweeps on
-    mixed-effort buckets (see ``BENCH_batch.json`` ``admission_policies``).
+    mixed-effort buckets (``tests/test_serving.py``,
+    ``test_residual_cobatching_cuts_wasted_sweeps``).
 
     No-starvation: a fresh bucket always seeds with the oldest head, and a
     head skipped by ``aging`` consecutive takes is force-admitted next, so

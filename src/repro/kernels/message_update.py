@@ -31,9 +31,9 @@ double-buffering of in/out streams).
 
 The kernel is batch-agnostic by construction: edges are an opaque 1-D grid
 axis, so a *bucket* of B same-shape graphs is served by folding the batch
-axis into the edge axis (E -> B*E, see ``repro.kernels.ops.
-pallas_update_batch``) -- one launch, full lane occupancy across graph
-boundaries, no per-graph block-remainder waste.
+axis into the edge axis (E -> B*E, the engine's disjoint-union fold,
+``repro.core.batch.BatchedPGM.folded``) -- one launch, full lane occupancy
+across graph boundaries, no per-graph block-remainder waste.
 
 Validated in ``interpret=True`` mode on CPU against ``ref.py`` (pure jnp).
 """

@@ -13,20 +13,17 @@ so ``BPConfig(backend="pallas_max")`` reaches it by name.
 loop, which keeps messages transposed (S, E) across rounds so the two
 transposes per round disappear (see EXPERIMENTS.md SSPerf, BP iterations).
 
-``pallas_update_batch`` is the bucket path: a ``BatchedPGM``'s (B, E) edges
-are folded into one (B*E,) edge axis so a single kernel launch -- one
-``pallas_call`` grid of B*E / BLK_E blocks -- covers the whole bucket,
-instead of B separate launches (or a vmap-added grid dimension with
-per-graph remainder waste). ``make_pallas_update_batch`` packages it as a
-``batch_update_fn`` for ``repro.core.batch.run_bp_batch``.
+A bucket needs no entry of its own: the engine folds a ``BatchedPGM``'s
+(B, E) edges into one (B*E,) disjoint union and calls the single-graph
+update on it, so one kernel launch covers the whole bucket.
 
-``triton_update`` / ``triton_update_batch`` are the GPU-class equivalents
+``triton_update`` is the GPU-class equivalent
 (``repro.kernels.triton_update``): same fused pipeline in the engine's
 native edge-major layout (zero boundary transposes), blocked over edges
 with states in registers, lowered through Pallas's Triton path on CUDA
 devices and through the interpreter on the CPU -- plus a
 ``semiring="max"`` mode so MAP workloads run fused too. Registered as
-``"triton"`` in both registries; ``BPConfig(backend="triton")`` reaches it.
+``"triton"``; ``BPConfig(backend="triton")`` reaches it.
 """
 
 from __future__ import annotations
@@ -42,10 +39,8 @@ from repro.core.registry import Registry
 from repro.kernels.message_update import fused_update_t, pick_block_edges
 from repro.kernels.triton_update import fused_update_e
 
-__all__ = ["UPDATE_BACKENDS", "BATCH_UPDATE_BACKENDS", "kernel_operands_t",
-           "pallas_update", "make_pallas_update", "pallas_update_batch",
-           "make_pallas_update_batch", "triton_update", "make_triton_update",
-           "triton_update_batch", "make_triton_update_batch",
+__all__ = ["UPDATE_BACKENDS", "kernel_operands_t", "pallas_update",
+           "make_pallas_update", "triton_update", "make_triton_update",
            "register_update_backend", "list_backends", "get_update_fn"]
 
 
@@ -116,44 +111,6 @@ def make_pallas_update(interpret: bool | None = None, *,
     return update_fn
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "semiring"))
-def pallas_update_batch(bpgm: PGM, logm: jax.Array, *,
-                        interpret: bool | None = None, semiring: str = "sum"):
-    """(cand (B,E,S), resid (B,E)) over a stacked element-PGM whose leaves
-    carry a leading batch axis (``BatchedPGM.pgm``). The batch axis is folded
-    into the kernel's edge axis: one launch, grid = ceil(B*E / BLK_E).
-    """
-    interpret = _resolve_interpret("pallas", interpret)
-    b, e, s = logm.shape
-    pre = jax.vmap(M.edge_prelude)(bpgm, logm)                # (B, E, S)
-    with jax.named_scope("bp.layout"):
-        # Fold batch into edges: graph b's edge e becomes folded edge
-        # b*E + e.
-        logpsi_t = jnp.transpose(bpgm.log_psi_e.reshape(b * e, s, s),
-                                 (1, 2, 0))
-        dmask = jax.vmap(lambda p: p.state_mask[p.edge_dst])(bpgm)
-        dmask_t = dmask.reshape(b * e, s).T                   # (S, B*E)
-        pre_t, logm_t = pre.reshape(b * e, s).T, logm.reshape(b * e, s).T
-    with jax.named_scope("bp.update"):
-        new_t, resid = fused_update_t(logpsi_t, pre_t, logm_t, dmask_t,
-                                      interpret=interpret, semiring=semiring)
-    with jax.named_scope("bp.layout"):
-        return new_t.T.reshape(b, e, s), resid.reshape(b, e)
-
-
-def make_pallas_update_batch(interpret: bool | None = None, *,
-                             semiring: str = "sum"):
-    """``batch_update_fn`` closure for the engine's batched path: whole-
-    bucket fused message update in one kernel launch."""
-    interpret = _resolve_interpret("pallas", interpret)
-
-    def batch_update_fn(bpgm: PGM, logm: jax.Array):
-        return pallas_update_batch(bpgm, logm, interpret=interpret,
-                                   semiring=semiring)
-
-    return batch_update_fn
-
-
 # ------------------------------------------------- triton (GPU) backend --
 
 @functools.partial(jax.jit, static_argnames=("interpret", "semiring",
@@ -185,45 +142,10 @@ def make_triton_update(interpret: bool | None = None, *,
     return update_fn
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "semiring",
-                                             "blk_e"))
-def triton_update_batch(bpgm: PGM, logm: jax.Array, *,
-                        interpret: bool | None = None, semiring: str = "sum",
-                        blk_e: int | None = None):
-    """(cand (B,E,S), resid (B,E)) bucket path: the batch axis folds into
-    the kernel's edge grid (one launch of ceil(B*E / BLK_E) programs), same
-    fold as ``pallas_update_batch`` but with zero transposes."""
-    interpret = _resolve_interpret("triton", interpret)
-    b, e, s = logm.shape
-    pre = jax.vmap(M.edge_prelude)(bpgm, logm)                # (B, E, S)
-    dmask = jax.vmap(lambda p: p.state_mask[p.edge_dst])(bpgm)
-    new, resid = fused_update_e(
-        bpgm.log_psi_e.reshape(b * e, s, s), pre.reshape(b * e, s),
-        logm.reshape(b * e, s), dmask.reshape(b * e, s),
-        semiring=semiring, interpret=interpret, blk_e=blk_e)
-    return new.reshape(b, e, s), resid.reshape(b, e)
-
-
-def make_triton_update_batch(interpret: bool | None = None, *,
-                             semiring: str = "sum", blk_e: int | None = None):
-    """``batch_update_fn`` closure: whole-bucket fused edge-major update in
-    one kernel launch (the ``"triton"`` batched registry entry)."""
-    interpret = _resolve_interpret("triton", interpret)
-
-    def batch_update_fn(bpgm: PGM, logm: jax.Array):
-        return triton_update_batch(bpgm, logm, interpret=interpret,
-                                   semiring=semiring, blk_e=blk_e)
-
-    return batch_update_fn
-
-
 # ------------------------------------------------- backend registry ------
 # Message-update backends addressable by BPConfig.backend string. "ref" is
 # the pure-jnp oracle; "pallas" the fused kernel (interpreted on the CPU);
 # "maxprod" and "pallas_max" the same pair for max-product.
-# Batched entries return a natively batched (B, E, S) update; the engine's
-# default batched path instead folds the bucket and reuses the single-graph
-# backend, so only register a batched entry when it beats the fold.
 
 def _make_sharded_update(**kwargs):
     # Lazy import: repro.dist builds on the engine, which resolves backends
@@ -258,29 +180,19 @@ UPDATE_BACKENDS = Registry("update backend", {
     "sharded": _make_sharded_update,
 })
 
-BATCH_UPDATE_BACKENDS = Registry("batched update backend", {
-    "pallas": make_pallas_update_batch,
-    "pallas_max": functools.partial(make_pallas_update_batch,
-                                    semiring="max"),
-    "triton": make_triton_update_batch,
-})
 
-
-def register_update_backend(name: str, *, batched: bool = False,
-                            overwrite: bool = False):
+def register_update_backend(name: str, *, overwrite: bool = False):
     """Decorator registering an update-backend factory under ``name``
     (lowercased). Duplicates raise ``ValueError`` unless ``overwrite=True``."""
-    registry = BATCH_UPDATE_BACKENDS if batched else UPDATE_BACKENDS
-    return registry.register(name, overwrite=overwrite)
+    return UPDATE_BACKENDS.register(name, overwrite=overwrite)
 
 
-def list_backends(*, batched: bool = False):
+def list_backends():
     """Sorted registered backend names (valid ``BPConfig.backend`` specs)."""
-    return (BATCH_UPDATE_BACKENDS if batched else UPDATE_BACKENDS).names()
+    return UPDATE_BACKENDS.names()
 
 
-def get_update_fn(name: str, *, batched: bool = False, **kwargs):
-    """Resolve a backend name to an update callable (see registries above).
-    ``kwargs`` (e.g. ``interpret=``) pass through to the factory."""
-    registry = BATCH_UPDATE_BACKENDS if batched else UPDATE_BACKENDS
-    return registry.lookup(name)(**kwargs)
+def get_update_fn(name: str, **kwargs):
+    """Resolve a backend name to an update callable (see the registry
+    above). ``kwargs`` (e.g. ``interpret=``) pass through to the factory."""
+    return UPDATE_BACKENDS.lookup(name)(**kwargs)

@@ -37,9 +37,7 @@ sizes BLK_E so one program's streamed working set stays under
 ``_GPU_WORKSET_BYTES`` (64 KiB -- two ``num_stages`` of that fit L1/SMEM on
 any modern part), clamped to power-of-two [8, 1024]; at S >= 32 the
 pairwise tile forces small blocks and low occupancy, exactly as the TPU
-VMEM budget does. ``autotune_blk_e`` measures candidates around that
-prediction; ``benchmarks/bench_kernel.py`` records predicted-vs-measured
-arithmetic intensity per scheduler into ``BENCH_kernel.json``.
+VMEM budget does.
 
 On the CPU the kernel runs in ``interpret=True`` mode (CPU CI exercises the
 same program through the Pallas interpreter), so ``BPConfig(
@@ -53,7 +51,6 @@ tile, ``num_stages=2`` for double-buffered HBM streaming).
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -74,8 +71,7 @@ _GPU_WORKSET_BYTES = 64 * 1024
 _MIN_BLK = 8
 _MAX_BLK = 1024
 
-__all__ = ["fused_update_e", "pick_block_edges_gpu", "autotune_blk_e",
-           "next_pow2"]
+__all__ = ["fused_update_e", "pick_block_edges_gpu", "next_pow2"]
 
 
 def next_pow2(n: int) -> int:
@@ -217,38 +213,3 @@ def fused_update_e(logpsi: jax.Array,   # (E, S, S) [e, x_src, x_dst]
     )(logpsi, pre, logm, dmask.astype(jnp.int8))
     return new[:e, :s], resid[:e]
 
-
-def autotune_blk_e(logpsi, pre, logm, dmask, *, semiring: str = "sum",
-                   interpret: bool = True, candidates=None, iters: int = 3):
-    """Measure ``fused_update_e`` wall time per power-of-two block size and
-    return ``(best_blk, {blk: mean_us})``.
-
-    Candidates default to the powers of two from 8 up to the roofline
-    picker's choice x4 (the model is a lower-bound traffic estimate, so the
-    measured optimum may sit above it). On CPU this times the interpreter
-    -- machinery exercise, not a GPU claim; on a CUDA device it times the
-    Triton lowering for real. ``bench_kernel`` records both the model pick
-    and the measured pick so drift is visible.
-    """
-    e, s = pre.shape
-    s_pad = max(2, next_pow2(s))
-    model = pick_block_edges_gpu(s_pad, jnp.dtype(pre.dtype).itemsize)
-    if candidates is None:
-        hi = min(_MAX_BLK, next_pow2(e), model * 4)
-        candidates, c = [], _MIN_BLK
-        while c <= hi:
-            candidates.append(c)
-            c *= 2
-    timings = {}
-    for blk in candidates:
-        out = fused_update_e(logpsi, pre, logm, dmask, semiring=semiring,
-                             interpret=interpret, blk_e=blk)
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = fused_update_e(logpsi, pre, logm, dmask, semiring=semiring,
-                                 interpret=interpret, blk_e=blk)
-            jax.block_until_ready(out)
-        timings[blk] = (time.perf_counter() - t0) / iters * 1e6
-    best = min(timings, key=timings.get)
-    return best, timings

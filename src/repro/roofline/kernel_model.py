@@ -33,8 +33,6 @@ not measured.
 The O(S) tail constants are fitted once against the traced kernel (exact
 at time of writing); ``tests/test_roofline.py`` pins model-vs-jaxpr
 agreement so neither the kernel body nor the walker can drift silently.
-``benchmarks/bench_kernel.py`` uses ``predicted_intensity`` as the
-autotune target and records predicted-vs-measured per scheduler.
 """
 
 from __future__ import annotations
@@ -108,9 +106,9 @@ def round_cost(pgm, scheduler, update_fn, *, eps: float = 1e-3,
                rng=None) -> Cost:
     """Jaxpr-walk cost of ONE full engine round -- fused update + residual
     gate + scheduler frontier selection + commit -- for a given scheduler
-    instance and update backend. This is what ``bench_kernel`` measures per
-    scheduler: the update kernel's intensity diluted by whatever selection
-    machinery the scheduler adds (top-k, per-queue bisection, RNG)."""
+    instance and update backend: the update kernel's intensity diluted by
+    whatever selection machinery the scheduler adds (top-k, per-queue
+    bisection, RNG)."""
     import jax
     import jax.numpy as jnp
 

@@ -15,11 +15,10 @@ Oracles and tolerances are per-backend:
   the fused kernel, so beliefs match to ~1e-4 and round counts to a small
   drift (residual-threshold crossings can shift by ulps).
 - ``sharded`` adds a cross-device edge split on top -- 5e-3.
-- ``maxprod`` and ``pallas_max`` are compared against
-  ``triton(semiring="max")`` -- true differential pairs (independent
-  implementations of the max semiring); max reductions are order-exact so
-  agreement is near-bitwise. A backend's semiring is read from
-  ``SEMIRING`` alone.
+- ``maxprod`` IS the max-product reference (pure jnp); ``pallas_max``, the
+  TPU kernel's max body, is compared against it. Max reductions are
+  order-exact, so agreement is near-bitwise. A backend's semiring is read
+  from ``SEMIRING`` alone.
 
 Also here: the chunked-resume bitwise contract per backend (N rounds via
 ``step`` == N rounds in one ``run``), serving-stack parity for the triton
@@ -52,7 +51,7 @@ from repro.core import messages as M
 from repro.core.graph import NEG_INF
 from repro.core.schedulers import list_schedulers
 from repro.kernels.message_update import fused_update_t
-from repro.kernels.ops import list_backends, make_triton_update
+from repro.kernels.ops import list_backends
 from repro.kernels.ref import fused_update_e_ref, fused_update_t_ref
 from repro.kernels.triton_update import fused_update_e
 from repro.pgm import chain_graph, ising_grid, ldpc_graph, stereo_graph
@@ -113,8 +112,7 @@ def oracle_result(scheduler, gname, semiring):
     the matching semiring. Cached -- many matrix cells share an oracle."""
     key = (scheduler, gname, semiring)
     if key not in _oracle_cache:
-        backend = "ref" if semiring == "sum" else \
-            make_triton_update(True, semiring="max")
+        backend = "ref" if semiring == "sum" else "maxprod"
         _oracle_cache[key] = _run(backend, scheduler, gname)
     return _oracle_cache[key]
 
@@ -213,22 +211,6 @@ class TestTritonServingStack:
             assert bool(r_ref.converged) and bool(r_tri.converged)
             np.testing.assert_allclose(np.asarray(r_tri.beliefs),
                                        np.asarray(r_ref.beliefs), atol=1e-4)
-
-    def test_native_batch_backend_matches_folded(self):
-        """The natively batched triton entry (batch axis folded into the
-        kernel's edge grid) is bitwise-equal to the engine's default fold
-        through the single-graph backend."""
-        import dataclasses
-        rng = jax.random.key(5)
-        base = BPConfig(scheduler="rnbp", eps=EPS, max_rounds=MAX_ROUNDS,
-                        history=False, backend="triton")
-        folded = BPEngine(base).run_many(self._stream(), rng)
-        native = BPEngine(dataclasses.replace(base, batch_backend="triton")) \
-            .run_many(self._stream(), rng)
-        for rf, rn in zip(folded, native):
-            np.testing.assert_array_equal(np.asarray(rf.logm),
-                                          np.asarray(rn.logm))
-            assert int(rf.rounds) == int(rn.rounds)
 
 
 def _edge_major_operands(rng, e, s, *, all_masked_frac=0.0):

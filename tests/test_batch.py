@@ -15,7 +15,7 @@ import pytest
 
 from repro.core import (BPConfig, BPEngine, LBP, RBP, RS, RnBP, BatchedPGM,
                         batch_keys, bucket_pgms, messages as M, pad_pgm)
-from repro.kernels.ops import make_pallas_update_batch, pallas_update_batch
+from repro.kernels.ops import pallas_update
 from repro.pgm import chain_graph, ising_grid, loop_graph, protein_like_graph
 
 SCHEDULERS = [LBP(), RBP(p=1.0 / 16), RS(p=0.05), RnBP(low_p=0.4, high_p=0.9)]
@@ -138,9 +138,12 @@ class TestFoldedUpdates:
         batch = BatchedPGM.from_pgms(
             [ising_grid(6, 2.0, seed=s) for s in range(3)]
             + [chain_graph(40, seed=7)])
+        b, e, s = batch.size, batch.n_edges, batch.n_states_max
         logm = jax.vmap(M.init_messages)(batch.pgm)
         c_ref, r_ref = jax.vmap(M.ref_update)(batch.pgm, logm)
-        c_k, r_k = pallas_update_batch(batch.pgm, logm, interpret=True)
+        c_k, r_k = pallas_update(batch.folded(), logm.reshape(b * e, s),
+                                 interpret=True)
+        c_k, r_k = c_k.reshape(b, e, s), r_k.reshape(b, e)
         mask = np.asarray(
             jax.vmap(lambda p: p.state_mask[p.edge_dst])(batch.pgm))
         np.testing.assert_allclose(
@@ -158,7 +161,6 @@ class TestFoldedUpdates:
         ref = engine(RnBP(), eps=1e-4, max_rounds=400,
                      history=False).run(batch, keys)
         ker = engine(RnBP(), eps=1e-4, max_rounds=400, history=False,
-                     batch_backend=make_pallas_update_batch(True)
-                     ).run(batch, keys)
+                     backend="pallas").run(batch, keys)
         assert bool(jnp.all(ker.converged))
         assert _belief_diff(ker.beliefs, ref.beliefs) < 1e-3

@@ -65,11 +65,11 @@ def test_md_code_blocks_execute(md, min_blocks):
     # registries; snapshot and restore so later tests see pristine families.
     from repro.core.schedulers import SCHEDULERS
     from repro.core.serving import ADMISSION_POLICIES
-    from repro.kernels.ops import BATCH_UPDATE_BACKENDS, UPDATE_BACKENDS
+    from repro.kernels.ops import UPDATE_BACKENDS
     from repro.pgm.datasets import WORKLOADS
     from repro.serve.routing import ROUTING_POLICIES
-    registries = (SCHEDULERS, UPDATE_BACKENDS, BATCH_UPDATE_BACKENDS,
-                  ADMISSION_POLICIES, ROUTING_POLICIES, WORKLOADS)
+    registries = (SCHEDULERS, UPDATE_BACKENDS, ADMISSION_POLICIES,
+                  ROUTING_POLICIES, WORKLOADS)
     snapshots = [dict(r) for r in registries]
     ns = {}
     try:

@@ -106,14 +106,13 @@ class TestConfigAndRegistry:
         from repro.serve import get_routing_policy, list_routing_policies
         assert "rlx" in list_schedulers() and "rlxtree" in list_schedulers()
         assert "sharded" in list_backends()
-        assert "pallas" in list_backends(batched=True)
+        assert "pallas" in list_backends()
         assert "fifo" in list_admission_policies()
         assert list_routing_policies() == ["deadline", "kind_affinity",
                                           "least_loaded", "round_robin"]
         fmt = r"unknown [\w ]+ 'nope'; registered: \["
         for fn in (lambda: get_scheduler("nope"),
                    lambda: get_update_fn("nope"),
-                   lambda: get_update_fn("nope", batched=True),
                    lambda: get_admission_policy("nope"),
                    lambda: get_routing_policy("nope")):
             with pytest.raises(KeyError) as ei:

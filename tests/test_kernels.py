@@ -158,8 +158,6 @@ class TestInterpretRule:
         assert ops._resolve_interpret(own, None) is False
         with pytest.raises(RuntimeError, match=repr(own)):
             get_update_fn(other)
-        with pytest.raises(RuntimeError, match=repr(own)):
-            get_update_fn(other, batched=True)
         # an explicit interpret= still wins
         assert callable(get_update_fn(other, interpret=True))
 

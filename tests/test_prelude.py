@@ -193,22 +193,17 @@ def _loop_gathers(graph, state_args, chunk, kw):
 
 
 @pytest.mark.parametrize("backend,batched", [
-    ("ref", False), ("ref", True), ("pallas", False), ("pallas", True),
-    ("pallas_batch", True)])
+    ("ref", False), ("ref", True), ("pallas", False), ("pallas", True)])
 def test_round_loop_gathers_no_source_mask(backend, batched):
     pgm = _graph("ising")
-    eng = BPEngine(BPConfig(
-        scheduler="rnbp", eps=1e-3, max_rounds=50,
-        backend="pallas" if backend == "pallas_batch" else backend,
-        batch_backend="pallas" if backend == "pallas_batch" else None))
+    eng = BPEngine(BPConfig(scheduler="rnbp", eps=1e-3, max_rounds=50,
+                            backend=backend))
     graph = BatchedPGM.from_pgms([pgm, _graph("ising", 1)]) if batched \
         else pgm
     state = eng.init(graph, jax.random.key(0))
     cfg = eng.config
     kw = dict(scheduler=eng.scheduler, damping=cfg.damping,
               update_fn=eng.update_fn, track_history=cfg.history)
-    if batched:
-        kw["batch_update_fn"] = eng.batch_update_fn
     chunk = E._chunk_batch if batched else E._chunk_single
     heard = []
 

@@ -84,6 +84,52 @@ class TestSweepClock:
             SweepClock(tau=-1.0)
 
 
+class TestOverloadAttainment:
+    """Deterministic overload on one lane, the whole stream staged at t=0:
+    the impossible pair first (head-of-line blocking), then the fast
+    backlog, then the express pair (tight budget) and the heavies (tight,
+    but feasible if served early). Only ``deadline`` admits by slack and
+    evicts the pair that can never converge, so it lands strictly more
+    requests inside their budgets than any other policy."""
+
+    SLO = {"impossible": 300.0, "fast": 2500.0, "express": 150.0,
+           "heavy": 600.0}
+    PIPE = dict(slots=1, max_batch=4, chunk_rounds=16, prefetch=None,
+                growth=2.0)
+    OTHERS = ("fifo", "residual", "windowed")
+
+    def _stream(self):
+        graphs = ([("impossible", ising_grid(6, 3.5, seed=s)) for s in (0, 2)]
+                  + [("fast", _fast(s)) for s in range(6)]
+                  + [("express", _fast(s)) for s in (10, 11)]
+                  + [("heavy", ising_grid(6, 2.2, seed=0)),
+                     ("heavy", ising_grid(6, 2.5, seed=4))])
+        return [(i, pgm, self.SLO[kind])
+                for i, (kind, pgm) in enumerate(graphs)]
+
+    @pytest.fixture(scope="class")
+    def reports(self, engine):
+        return {policy: serve_async(engine, iter(self._stream()),
+                                    jax.random.key(0), admission=policy,
+                                    clock=SweepClock(), **self.PIPE)
+                for policy in self.OTHERS + ("deadline",)}
+
+    @pytest.mark.parametrize("other", OTHERS)
+    def test_deadline_attains_strictly_more(self, reports, other):
+        def attained(policy):
+            return sum(r.within_slo for r in reports[policy].records)
+
+        assert attained("deadline") > attained(other)
+
+    def test_deadline_evicts_only_the_impossible_pair(self, reports):
+        evicted = sorted(r.rid for r in reports["deadline"].records
+                         if r.evicted)
+        assert evicted == [0, 1]
+        assert reports["deadline"].stats.evictions == 2
+        for policy in self.OTHERS:
+            assert not any(r.evicted for r in reports[policy].records)
+
+
 class TestDeadlineDeterminism:
     """Acceptance: under an injected SweepClock the full serving timeline
     (admission order, sync times, evictions) is run-to-run identical --

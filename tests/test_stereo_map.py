@@ -83,11 +83,11 @@ def test_pallas_update_max_equals_max_product_update():
                                   np.asarray(want_r)[real])
 
 
-def _engine(backend, **kw):
+def _engine(backend):
     return BPEngine(BPConfig(scheduler="rnbp",
                              scheduler_kwargs={"low_p": 0.7, "high_p": 1.0},
                              eps=1e-3, max_rounds=500, history=False,
-                             backend=backend, **kw))
+                             backend=backend))
 
 
 def test_pallas_max_follows_maxprod_round_for_round():
@@ -102,20 +102,6 @@ def test_pallas_max_follows_maxprod_round_for_round():
                                   np.asarray(want.logm))
     np.testing.assert_array_equal(np.asarray(got.beliefs),
                                   np.asarray(want.beliefs))
-
-
-def test_batched_pallas_max_equals_the_fold():
-    """The bucket entry (one launch over the folded batch) commits what the
-    engine's default fold through the single-graph backend commits."""
-    pgms = [stereo_pair_mrf(5, 6, 4, seed=s).pgm for s in (0, 1, 2)]
-    key = jax.random.key(2)
-    folded = _engine("pallas_max").run_many(pgms, key)
-    native = _engine("pallas_max", batch_backend="pallas_max") \
-        .run_many(pgms, key)
-    for a, b in zip(folded, native):
-        assert bool(a.converged)
-        np.testing.assert_array_equal(np.asarray(a.logm), np.asarray(b.logm))
-        assert int(a.rounds) == int(b.rounds)
 
 
 @pytest.mark.parametrize("n_disp", [3, 4])

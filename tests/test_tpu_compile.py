@@ -23,10 +23,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.pgm import ising_grid_fast, stereo_graph  # first: repro.core
-from repro.core import BPConfig, BPEngine
+from repro.core import BPConfig, BPEngine, BatchedPGM
 from repro.core import engine as E
 from repro.kernels.message_update import fused_update_t
-from repro.kernels.ops import make_pallas_update, pallas_update_batch
+from repro.kernels.ops import make_pallas_update
 
 #: (S, E): states and edges of the kernel call at real sizes.
 KERNEL_SHAPES = {
@@ -92,13 +92,15 @@ def test_max_body_compiles_for_v5e(one_chip, name):
 
 
 def test_bucket_fold_compiles_for_v5e(one_chip):
-    """A bucket of 4 stereo graphs folds into one kernel launch."""
+    """A bucket of 4 stereo graphs folds into one kernel launch: the
+    engine's disjoint-union fold through the single-graph update."""
     pgm = stereo_graph(seed=0, height=12, width=16, n_disp=8)
     bpgm = jax.tree.map(
         lambda x: _spec((4,) + x.shape, x.dtype, one_chip), pgm)
-    logm = _spec((4, pgm.n_edges, pgm.n_states_max), jnp.float32, one_chip)
-    compiled = pallas_update_batch.lower(bpgm, logm,
-                                         interpret=False).compile()
+    logm = _spec((4 * pgm.n_edges, pgm.n_states_max), jnp.float32, one_chip)
+    update = make_pallas_update(interpret=False)
+    fold = jax.jit(lambda p, m: update(BatchedPGM(p).folded(), m))
+    compiled = fold.lower(bpgm, logm).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 

@@ -38,8 +38,7 @@ def _chunk_text(eng, state):
     limit = jnp.minimum(state.rounds + cfg.max_rounds, cfg.max_rounds)
     if state.batched:
         lowered = E._chunk_batch.lower(
-            state.graph, E._carry_of(state), limit, cfg.eps,
-            batch_update_fn=eng.batch_update_fn, **kw)
+            state.graph, E._carry_of(state), limit, cfg.eps, **kw)
     else:
         lowered = E._chunk_single.lower(state.graph, E._carry_of(state),
                                         limit, cfg.eps, **kw)

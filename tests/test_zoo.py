@@ -245,6 +245,32 @@ class TestStereoConformance:
         assert err < 2e-2, f"{sched}: max marginal error {err:.3e}"
 
 
+class TestStereoDecode:
+    """Max-product decoding of a 12x16 stereo grid with 8 disparities, per
+    registered scheduler: the decoded labelling's energy is at most the
+    ground truth's (MAP is what it optimizes), and its +-1 accuracy is at
+    least the rounded observation's."""
+
+    H, W, D = 12, 16, 8
+
+    @pytest.fixture(scope="class")
+    def inst(self):
+        return stereo_mrf(self.H, self.W, self.D, seed=0)
+
+    @pytest.mark.parametrize("sched", list_schedulers())
+    def test_map_energy_and_accuracy_beat_truth_and_observation(self, sched,
+                                                                inst):
+        engine = BPEngine(BPConfig(scheduler=sched, backend="maxprod",
+                                   eps=1e-4, max_rounds=2000, history=False))
+        res = engine.run(inst.pgm, jax.random.key(0))
+        assert bool(res.converged), f"{sched} did not converge"
+        labels = np.asarray(map_assignment(inst.pgm, res.logm))
+        labels = labels[: self.H * self.W]
+        obs = np.clip(np.round(inst.obs), 0, self.D - 1).astype(int)
+        assert inst.energy(labels) <= inst.energy(inst.truth)
+        assert inst.accuracy(labels) >= inst.accuracy(obs)
+
+
 class TestHeterogeneousStream:
     """The tentpole regression: the mixed zoo stream served online is
     bitwise identical per request to solo runs on identically padded
